@@ -98,6 +98,9 @@ def bench_distributed(rows: List[Dict], smoke: bool = False) -> None:
     n = 1 << 12 if smoke else 1 << 16
     iters = 2 if smoke else 5
     env = dict(os.environ)
+    # the forced host mesh is CPU devices: the child must never reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     # src for repro, repo root for benchmarks._timing
     env["PYTHONPATH"] = os.path.join(_ROOT, "src") + os.pathsep + _ROOT

@@ -25,6 +25,10 @@ def main() -> None:
     parser.add_argument("--json", default="", help="also write rows as JSON to this path")
     args = parser.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks.bench_merge import (
         bench_batched_merge,
         bench_load_balance,

@@ -32,10 +32,10 @@ don't care get measured defaults and consumers that do (serving sampler,
 MoE dispatch, distributed sort) can pass their own.
 
 **Interpret default**: ``interpret=None`` (the default everywhere)
-resolves to the module-level :data:`DEFAULT_INTERPRET`, which is ``True``
-(interpret mode) unless the ``REPRO_PALLAS_INTERPRET`` environment
-variable says otherwise — set ``REPRO_PALLAS_INTERPRET=0`` on a real TPU
-and every call site in the repo compiles, no call-site edits needed.
+resolves through :func:`default_interpret` when the call is made:
+compiled kernels on a TPU backend, the Pallas interpreter on any other.
+A non-empty ``REPRO_PALLAS_INTERPRET`` overrides the backend rule
+(``0`` compiled, ``1`` interpreted) — tests use it, nothing else needs it.
 
 **NaN keys**: the float sort / top-k paths compare
 :func:`repro.core.merge_path.total_order_keys` of the keys (same-width
@@ -76,10 +76,11 @@ from repro.runtime import resilience as _res
 from . import merge_path as _kern
 from . import tune as _tune
 
-# single source of truth for the env-overridable interpret default — the
-# kernel wrappers, tune.autotune, and the benchmarks all resolve through
-# it (re-exported here because ops is the public dispatch surface)
-DEFAULT_INTERPRET: bool = _kern.DEFAULT_INTERPRET
+# single source of truth for the interpret default (compiled on a TPU
+# backend, interpreted elsewhere, ``REPRO_PALLAS_INTERPRET`` overrides) —
+# the kernel wrappers, tune.autotune and the benchmarks all resolve
+# through it (re-exported here because ops is the public dispatch surface)
+default_interpret = _kern.default_interpret
 _interp = _kern._interp
 
 
@@ -545,24 +546,23 @@ def merge_kv_batched_ragged(
 
 
 def _sort_rounds(flat: jax.Array, m: int, tile: int, leaf: int, engine: str, interpret: bool) -> jax.Array:
-    """Bottom-up merge-sort rounds over a flat ``(B * m,)`` buffer of
-    width-1 runs (``m`` = per-row pow2 width; pairs never straddle a row
-    because ``m`` is a multiple of every round width).
+    """Bottom-up merge-sort rounds over a flat ``(B * m,)`` buffer
+    (``m`` = per-row pow2 width; pairs never straddle a row because ``m``
+    is a multiple of every round width).
 
-    Narrow rounds (``2 * width <= tile``) are fused pure-JAX batched
-    merges on reshaped views; wide rounds are flat-kernel launches
-    sharing ONE sentinel tail appended here, once — the padding hoist
-    that used to happen per round inside ``_prepare_batched``.
+    The first ``log2(tile)`` rounds — runs narrower than one tile — are
+    one stable sort of every ``tile``-wide block (a stable sort of a block
+    IS its bottom-up merge, so results are bit-identical); merged
+    round by round in XLA they needed GiBs of temporaries at 2^24 keys.
+    Wide rounds are flat-kernel launches sharing ONE sentinel tail
+    appended here, once.
     """
-    width = 1
-    while width < m and 2 * width <= tile:
-        runs = flat.reshape(-1, 2, width)
-        flat = _bat.merge_batched(runs[:, 0], runs[:, 1]).reshape(-1)
-        width *= 2
+    width = min(tile, m)
+    flat = jax.lax.sort(flat.reshape(-1, width), dimension=1, is_stable=True).reshape(-1)
     if width < m:
         total = flat.shape[0]
         xf = jnp.concatenate(
-            [flat, jnp.full((tile,), _mp.max_sentinel(flat.dtype), flat.dtype)]
+            [flat, jnp.full((_kern.sort_tail(tile),), _mp.max_sentinel(flat.dtype), flat.dtype)]
         )
         while width < m:
             xf = _kern.sort_round_pallas(
@@ -577,19 +577,17 @@ def _sort_rounds_kv(
     kflat: jax.Array, vflat: jax.Array, m: int, tile: int, leaf: int, engine: str, interpret: bool
 ) -> Tuple[jax.Array, jax.Array]:
     """Key-value :func:`_sort_rounds` (values' hoisted tail is zeros)."""
-    width = 1
-    while width < m and 2 * width <= tile:
-        kr = kflat.reshape(-1, 2, width)
-        vr = vflat.reshape(-1, 2, width)
-        kflat, vflat = _bat.merge_kv_batched(kr[:, 0], vr[:, 0], kr[:, 1], vr[:, 1])
-        kflat, vflat = kflat.reshape(-1), vflat.reshape(-1)
-        width *= 2
+    width = min(tile, m)
+    kflat, vflat = jax.lax.sort(
+        (kflat.reshape(-1, width), vflat.reshape(-1, width)), dimension=1, is_stable=True, num_keys=1
+    )
+    kflat, vflat = kflat.reshape(-1), vflat.reshape(-1)
     if width < m:
         total = kflat.shape[0]
         kf = jnp.concatenate(
-            [kflat, jnp.full((tile,), _mp.max_sentinel(kflat.dtype), kflat.dtype)]
+            [kflat, jnp.full((_kern.sort_tail(tile),), _mp.max_sentinel(kflat.dtype), kflat.dtype)]
         )
-        vf = jnp.concatenate([vflat, jnp.zeros((tile,), vflat.dtype)])
+        vf = jnp.concatenate([vflat, jnp.zeros((_kern.sort_tail(tile),), vflat.dtype)])
         while width < m:
             kf, vf = _kern.sort_round_kv_pallas(
                 kf, vf, width, tile=tile, leaf=leaf, engine=engine, interpret=interpret

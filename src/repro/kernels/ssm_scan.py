@@ -78,6 +78,12 @@ def ssm_scan_ref(dt, x, bmat, cmat, a):
 
 def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hlast_ref, *rest,
             chunk: int, checkpoints: bool):
+    """Forward over one (batch row, d-tile, seq chunk) cell.
+
+    Operands are f32.  The state is kept transposed, ``(st, d_tile)``:
+    ``d_tile`` on the lanes, so a state slab holds no lane padding (a
+    ``(d_tile, st=16)`` slab would be 8x its size in VMEM).
+    """
     if checkpoints:
         hstart_ref, h_scr = rest
     else:
@@ -93,17 +99,15 @@ def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hlast_ref, *rest,
         # state at the START of this chunk — what the backward recomputes from
         hstart_ref[0, 0] = h_scr[...]
 
-    a = a_ref[...].astype(jnp.float32)  # (d_tile, st)
+    a = a_ref[...]  # (st, d_tile)
 
     def body(i, h):
-        dt_i = dt_ref[0, i, :].astype(jnp.float32)  # (d_tile,)
-        x_i = x_ref[0, i, :].astype(jnp.float32)
-        b_i = b_ref[0, i, :].astype(jnp.float32)  # (st,)
-        c_i = c_ref[0, i, :].astype(jnp.float32)
-        decay = jnp.exp(dt_i[:, None] * a)  # (d_tile, st)
-        upd = (dt_i * x_i)[:, None] * b_i[None, :]
-        h = decay * h + upd
-        y_ref[0, i, :] = jnp.sum(h * c_i[None, :], axis=1).astype(y_ref.dtype)
+        dt_i = dt_ref[0, pl.ds(i, 1), :]  # (1, d_tile)
+        x_i = x_ref[0, pl.ds(i, 1), :]
+        b_i = b_ref[0, i, :][:, None]  # (st, 1)
+        c_i = c_ref[0, i, :][:, None]
+        h = jnp.exp(dt_i * a) * h + b_i * (dt_i * x_i)
+        y_ref[0, pl.ds(i, 1), :] = jnp.sum(h * c_i, axis=0, keepdims=True)
         return h
 
     h = jax.lax.fori_loop(0, chunk, body, h_scr[...])
@@ -114,8 +118,11 @@ def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hlast_ref, *rest,
         hlast_ref[0] = h
 
 
-def _fwd_call(dt, x, bmat, cmat, a, chunk: int, d_tile: int, interpret: bool,
+def _fwd_call(dt, x, bmat, cmat, a_t, chunk: int, d_tile: int, interpret: bool,
               checkpoints: bool):
+    """Forward launch; ``a_t`` is ``A`` transposed ``(st, D)``, and the
+    states it returns are transposed too: ``h_final (B, st, D)``,
+    checkpoints ``(B, S/chunk, st, D)``."""
     bsz, s, d = x.shape
     st = bmat.shape[-1]
     assert s % chunk == 0, (s, chunk)
@@ -125,17 +132,17 @@ def _fwd_call(dt, x, bmat, cmat, a, chunk: int, d_tile: int, interpret: bool,
 
     out_specs = [
         pl.BlockSpec((1, chunk, d_tile), lambda b, dd, ss: (b, ss, dd)),  # y
-        pl.BlockSpec((1, d_tile, st), lambda b, dd, ss: (b, dd, 0)),  # h_final
+        pl.BlockSpec((1, st, d_tile), lambda b, dd, ss: (b, 0, dd)),  # h_final
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((bsz, s, d), x.dtype),
-        jax.ShapeDtypeStruct((bsz, d, st), jnp.float32),
+        jax.ShapeDtypeStruct((bsz, s, d), jnp.float32),
+        jax.ShapeDtypeStruct((bsz, st, d), jnp.float32),
     ]
     if checkpoints:
         out_specs.append(
-            pl.BlockSpec((1, 1, d_tile, st), lambda b, dd, ss: (b, ss, dd, 0))
+            pl.BlockSpec((1, 1, st, d_tile), lambda b, dd, ss: (b, ss, 0, dd))
         )
-        out_shape.append(jax.ShapeDtypeStruct((bsz, n_s, d, st), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((bsz, n_s, st, d), jnp.float32))
 
     return pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, checkpoints=checkpoints),
@@ -145,13 +152,13 @@ def _fwd_call(dt, x, bmat, cmat, a, chunk: int, d_tile: int, interpret: bool,
             pl.BlockSpec((1, chunk, d_tile), lambda b, dd, ss: (b, ss, dd)),  # x
             pl.BlockSpec((1, chunk, st), lambda b, dd, ss: (b, ss, 0)),  # B
             pl.BlockSpec((1, chunk, st), lambda b, dd, ss: (b, ss, 0)),  # C
-            pl.BlockSpec((d_tile, st), lambda b, dd, ss: (dd, 0)),  # A
+            pl.BlockSpec((st, d_tile), lambda b, dd, ss: (0, dd)),  # A^T
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((d_tile, st), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((st, d_tile), jnp.float32)],
         interpret=interpret,
-    )(dt, x, bmat, cmat, a)
+    )(dt, x, bmat, cmat, a_t)
 
 
 def _bwd_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, hstart_ref, dy_ref, dhfin_ref,
@@ -164,7 +171,8 @@ def _bwd_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, hstart_ref, dy_ref, dhfin_re
     the d-tile axis innermost so the dB/dC partial sums over d-tiles
     accumulate into a block that stays VMEM-resident between consecutive
     grid steps.  Per-(b, d-tile) reverse carries live in scratch slabs
-    indexed by the d-tile id.
+    indexed by the d-tile id.  States, carries and dA are ``(st, d_tile)``
+    (transposed, lane-dense) as in the forward.
     """
     b_idx = pl.program_id(0)
     s_idx = pl.program_id(1)  # 0 == LAST seq chunk (reversed index maps)
@@ -172,68 +180,55 @@ def _bwd_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, hstart_ref, dy_ref, dhfin_re
     n_b = pl.num_programs(0)
     n_s = pl.num_programs(1)
 
-    a = a_ref[...].astype(jnp.float32)  # (d_tile, st)
-    st = a.shape[-1]
+    a = a_ref[...]  # (st, d_tile)
 
     # 1) recompute this chunk's states from the checkpoint:
     #    h_scr[i] = state BEFORE step i (h_scr[chunk] = state after the chunk)
     def fwd_body(i, h):
         h_scr[i] = h
-        dt_i = dt_ref[0, i, :].astype(jnp.float32)
-        x_i = x_ref[0, i, :].astype(jnp.float32)
-        b_i = b_ref[0, i, :].astype(jnp.float32)
-        decay = jnp.exp(dt_i[:, None] * a)
-        return decay * h + (dt_i * x_i)[:, None] * b_i[None, :]
+        dt_i = dt_ref[0, pl.ds(i, 1), :]
+        x_i = x_ref[0, pl.ds(i, 1), :]
+        b_i = b_ref[0, i, :][:, None]
+        return jnp.exp(dt_i * a) * h + b_i * (dt_i * x_i)
 
-    h_last = jax.lax.fori_loop(
-        0, chunk, fwd_body, hstart_ref[0, 0].astype(jnp.float32)
-    )
-    h_scr[chunk] = h_last
+    h_scr[chunk] = jax.lax.fori_loop(0, chunk, fwd_body, hstart_ref[0, 0])
 
     # 2) reverse accumulation; g = dL/dh_t carried right-to-left
     @pl.when(s_idx == 0)
     def _init_g():
-        g_scr[d_idx] = dhfin_ref[0].astype(jnp.float32)
+        g_scr[d_idx] = dhfin_ref[0]
 
-    def bwd_body(i, carry):
-        g, db_acc, dc_acc, da_acc = carry
-        t = chunk - 1 - i
-        dt_t = dt_ref[0, t, :].astype(jnp.float32)  # (d_tile,)
-        x_t = x_ref[0, t, :].astype(jnp.float32)
-        b_t = b_ref[0, t, :].astype(jnp.float32)  # (st,)
-        c_t = c_ref[0, t, :].astype(jnp.float32)
-        dy_t = dy_ref[0, t, :].astype(jnp.float32)  # (d_tile,)
-        h_prev = h_scr[t]  # (d_tile, st)
-        h_t = h_scr[t + 1]
-        dc_acc = dc_acc.at[t].set(jnp.sum(h_t * dy_t[:, None], axis=0))
-        g = g + dy_t[:, None] * c_t[None, :]
-        decay = jnp.exp(dt_t[:, None] * a)
-        gdec = g * h_prev * decay  # = dL/d(dt_t ⊗ a), chained through exp
-        s_gb = jnp.sum(g * b_t[None, :], axis=1)  # (d_tile,) = dL/d(dt_t * x_t)
-        ddt_ref[0, t, :] = jnp.sum(gdec * a, axis=1) + x_t * s_gb
-        dx_ref[0, t, :] = dt_t * s_gb
-        db_acc = db_acc.at[t].set(jnp.sum(g * (dt_t * x_t)[:, None], axis=0))
-        da_acc = da_acc + dt_t[:, None] * gdec
-        g = g * decay
-        return g, db_acc, dc_acc, da_acc
-
-    zeros_cs = jnp.zeros((chunk, st), jnp.float32)
-    g, db_acc, dc_acc, da_acc = jax.lax.fori_loop(
-        0, chunk, bwd_body, (g_scr[d_idx], zeros_cs, zeros_cs, jnp.zeros_like(a))
-    )
-    g_scr[d_idx] = g
-
-    # dB/dC: partial sums over this d-tile; the (b, chunk) output block is
+    # dB/dC: partial sums over d-tiles; the (b, chunk) output block is
     # revisited consecutively as d_idx advances, so accumulate in place
     @pl.when(d_idx == 0)
     def _db_init():
-        db_ref[0] = db_acc
-        dc_ref[0] = dc_acc
+        db_ref[...] = jnp.zeros_like(db_ref)
+        dc_ref[...] = jnp.zeros_like(dc_ref)
 
-    @pl.when(d_idx > 0)
-    def _db_acc():
-        db_ref[0] = db_ref[0] + db_acc
-        dc_ref[0] = dc_ref[0] + dc_acc
+    def bwd_body(i, carry):
+        g, da_acc = carry
+        t = chunk - 1 - i
+        dt_t = dt_ref[0, pl.ds(t, 1), :]  # (1, d_tile)
+        x_t = x_ref[0, pl.ds(t, 1), :]
+        dy_t = dy_ref[0, pl.ds(t, 1), :]
+        b_t = b_ref[0, t, :][:, None]  # (st, 1)
+        c_t = c_ref[0, t, :][:, None]
+        h_prev = h_scr[t]  # (st, d_tile)
+        h_t = h_scr[t + 1]
+        dc_ref[0, t, :] = dc_ref[0, t, :] + jnp.sum(h_t * dy_t, axis=1)
+        g = g + c_t * dy_t
+        decay = jnp.exp(dt_t * a)
+        gdec = g * h_prev * decay  # = dL/d(dt_t ⊗ a), chained through exp
+        s_gb = jnp.sum(g * b_t, axis=0, keepdims=True)  # (1, d_tile) = dL/d(dt_t * x_t)
+        ddt_ref[0, pl.ds(t, 1), :] = jnp.sum(gdec * a, axis=0, keepdims=True) + x_t * s_gb
+        dx_ref[0, pl.ds(t, 1), :] = dt_t * s_gb
+        db_ref[0, t, :] = db_ref[0, t, :] + jnp.sum(g * (dt_t * x_t), axis=1)
+        da_acc = da_acc + dt_t * gdec
+        g = g * decay
+        return g, da_acc
+
+    g, da_acc = jax.lax.fori_loop(0, chunk, bwd_body, (g_scr[d_idx], jnp.zeros_like(a)))
+    g_scr[d_idx] = g
 
     # dA: accumulated over batch AND seq in scratch, written once at the
     # final visit of this d-tile
@@ -252,8 +247,10 @@ def _bwd_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, hstart_ref, dy_ref, dhfin_re
         da_ref[...] = da_scr[d_idx]
 
 
-def _bwd_call(dt, x, bmat, cmat, a, hstart, dy, dhfin,
+def _bwd_call(dt, x, bmat, cmat, a_t, hstart, dy, dhfin_t,
               chunk: int, d_tile: int, interpret: bool):
+    """Backward launch on the transposed state layout of :func:`_fwd_call`
+    (``a_t (st, D)``, ``dhfin_t (B, st, D)``); returns dA as ``(st, D)``."""
     bsz, s, d = x.shape
     st = bmat.shape[-1]
     n_s = s // chunk
@@ -270,68 +267,71 @@ def _bwd_call(dt, x, bmat, cmat, a, hstart, dy, dhfin,
             pl.BlockSpec((1, chunk, d_tile), lambda b, ss, dd: (b, rev(ss), dd)),  # x
             pl.BlockSpec((1, chunk, st), lambda b, ss, dd: (b, rev(ss), 0)),  # B
             pl.BlockSpec((1, chunk, st), lambda b, ss, dd: (b, rev(ss), 0)),  # C
-            pl.BlockSpec((d_tile, st), lambda b, ss, dd: (dd, 0)),  # A
-            pl.BlockSpec((1, 1, d_tile, st), lambda b, ss, dd: (b, rev(ss), dd, 0)),  # hstart
+            pl.BlockSpec((st, d_tile), lambda b, ss, dd: (0, dd)),  # A^T
+            pl.BlockSpec((1, 1, st, d_tile), lambda b, ss, dd: (b, rev(ss), 0, dd)),  # hstart
             pl.BlockSpec((1, chunk, d_tile), lambda b, ss, dd: (b, rev(ss), dd)),  # dy
-            pl.BlockSpec((1, d_tile, st), lambda b, ss, dd: (b, dd, 0)),  # dhfin
+            pl.BlockSpec((1, st, d_tile), lambda b, ss, dd: (b, 0, dd)),  # dhfin^T
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, d_tile), lambda b, ss, dd: (b, rev(ss), dd)),  # ddt
             pl.BlockSpec((1, chunk, d_tile), lambda b, ss, dd: (b, rev(ss), dd)),  # dx
             pl.BlockSpec((1, chunk, st), lambda b, ss, dd: (b, rev(ss), 0)),  # dB
             pl.BlockSpec((1, chunk, st), lambda b, ss, dd: (b, rev(ss), 0)),  # dC
-            pl.BlockSpec((d_tile, st), lambda b, ss, dd: (dd, 0)),  # dA
+            pl.BlockSpec((st, d_tile), lambda b, ss, dd: (0, dd)),  # dA^T
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, s, d), f32),
             jax.ShapeDtypeStruct((bsz, s, d), f32),
             jax.ShapeDtypeStruct((bsz, s, st), f32),
             jax.ShapeDtypeStruct((bsz, s, st), f32),
-            jax.ShapeDtypeStruct((d, st), f32),
+            jax.ShapeDtypeStruct((st, d), f32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((chunk + 1, d_tile, st), f32),  # recomputed chunk states
-            pltpu.VMEM((n_d, d_tile, st), f32),  # g carry, one slab per d-tile
-            pltpu.VMEM((n_d, d_tile, st), f32),  # dA accumulator per d-tile
+            pltpu.VMEM((chunk + 1, st, d_tile), f32),  # recomputed chunk states
+            pltpu.VMEM((n_d, st, d_tile), f32),  # g carry, one slab per d-tile
+            pltpu.VMEM((n_d, st, d_tile), f32),  # dA accumulator per d-tile
         ],
         interpret=interpret,
-    )(dt, x, bmat, cmat, a, hstart, dy, dhfin)
+    )(dt, x, bmat, cmat, a_t, hstart, dy, dhfin_t)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _ssm_scan(dt, x, bmat, cmat, a, chunk, d_tile, interpret):
-    y, h_final = _fwd_call(dt, x, bmat, cmat, a, chunk, d_tile, interpret,
-                           checkpoints=False)
-    return y, h_final
+    y, h_final_t = _fwd_call(dt, x, bmat, cmat, a.T, chunk, d_tile, interpret,
+                             checkpoints=False)
+    return y, jnp.swapaxes(h_final_t, 1, 2)
 
 
 def _ssm_scan_fwd(dt, x, bmat, cmat, a, chunk, d_tile, interpret):
-    y, h_final, hstart = _fwd_call(dt, x, bmat, cmat, a, chunk, d_tile, interpret,
-                                   checkpoints=True)
-    return (y, h_final), (dt, x, bmat, cmat, a, hstart)
+    y, h_final_t, hstart = _fwd_call(dt, x, bmat, cmat, a.T, chunk, d_tile, interpret,
+                                     checkpoints=True)
+    return (y, jnp.swapaxes(h_final_t, 1, 2)), (dt, x, bmat, cmat, a, hstart)
 
 
 def _ssm_scan_bwd(chunk, d_tile, interpret, res, cts):
     dt, x, bmat, cmat, a, hstart = res
     dy, dhfin = cts
-    ddt, dx, db, dc, da = _bwd_call(
-        dt, x, bmat, cmat, a, hstart, dy, dhfin, chunk, d_tile, interpret
+    ddt, dx, db, dc, da_t = _bwd_call(
+        dt, x, bmat, cmat, a.T, hstart, dy, jnp.swapaxes(dhfin, 1, 2), chunk, d_tile, interpret
     )
-    return (
-        ddt.astype(dt.dtype),
-        dx.astype(x.dtype),
-        db.astype(bmat.dtype),
-        dc.astype(cmat.dtype),
-        da.astype(a.dtype),
-    )
+    return ddt, dx, db, dc, da_t.T
 
 
 _ssm_scan.defvjp(_ssm_scan_fwd, _ssm_scan_bwd)
 
 
 def _ssm_scan_launch(dt, x, bmat, cmat, a, chunk, d_tile, interpret):
-    """Pad-to-chunk + fused-kernel dispatch (the guarded primary attempt)."""
+    """Pad-to-chunk + fused-kernel dispatch (the guarded primary attempt).
+
+    The kernels compute in f32 and take f32 operands (a row of a packed
+    bf16 block cannot be addressed at a dynamic step): narrower inputs are
+    widened here, ``y`` is narrowed back to ``x.dtype``, and the casts'
+    VJPs return each gradient in its input's dtype.
+    """
     bsz, s, d = x.shape
+    out_dtype = x.dtype
+    f32 = jnp.float32
+    dt, x, bmat, cmat, a = (t.astype(f32) for t in (dt, x, bmat, cmat, a))
     pad = (-s) % chunk
     if pad:
         widen = lambda t: jnp.pad(t, ((0, 0), (0, pad), (0, 0)))  # noqa: E731
@@ -339,7 +339,7 @@ def _ssm_scan_launch(dt, x, bmat, cmat, a, chunk, d_tile, interpret):
     y, h_final = _ssm_scan(dt, x, bmat, cmat, a, chunk, d_tile, interpret)
     if pad:
         y = y[:, :s]
-    return y, h_final
+    return y.astype(out_dtype), h_final
 
 
 @kernel_contract(kind="scan", batched=True, differentiable=True)
@@ -360,8 +360,8 @@ def ssm_scan_pallas(
     (see module docstring).  ``S`` need not divide ``chunk``: the tail is
     padded with identity steps (``dt = 0`` ⇒ ``decay = 1, upd = 0``), so
     ``h_final`` and the trimmed ``y`` — and their gradients — are exact.
-    ``interpret=None`` resolves through ``REPRO_PALLAS_INTERPRET`` like
-    every :mod:`repro.kernels.ops` wrapper.
+    ``interpret=None`` follows the backend (compiled on a TPU) like every
+    :mod:`repro.kernels.ops` wrapper.
 
     Eager calls route through guarded dispatch: preflight checks the scan
     VMEM model against the A005 budget, and a launch failure degrades to

@@ -9,8 +9,8 @@ consults a small micro-bench table:
 
 * ``DEFAULT_TABLE`` ships with the repo — measured with
   :func:`build_table` in interpret mode on the dev container (regenerate
-  with ``python -m repro.kernels.tune``; on a real TPU run it once with
-  ``REPRO_PALLAS_INTERPRET=0`` and commit the result).
+  with ``python -m repro.kernels.tune``; run on a TPU it measures the
+  compiled kernels — commit that result).
 * :func:`autotune` re-measures one ``(dtype, size)`` cell over a
   candidate grid and updates the in-process table, for callers whose
   workload is hot enough to warrant a startup sweep.
@@ -126,10 +126,9 @@ def autotune(
     variants share the same tile body, so the optimum transfers).  With
     ``update_table`` (default) the result is written into the in-process
     table, so subsequent :func:`pick` calls in the same bucket use it.
-    ``interpret=None`` follows ``REPRO_PALLAS_INTERPRET`` like every
-    kernel wrapper, so regenerating the table on a real TPU
-    (``REPRO_PALLAS_INTERPRET=0 python -m repro.kernels.tune``) measures
-    compiled kernels, not the interpreter.
+    ``interpret=None`` follows the backend like every kernel wrapper, so
+    regenerating the table on a TPU (``python -m repro.kernels.tune``)
+    measures compiled kernels, not the interpreter.
     """
     interpret = _interp(interpret)
     a, b = _probe_pair(n, dtype)

@@ -24,6 +24,7 @@ from repro.configs import TrainConfig, get_config
 from repro.data.pipeline import PipelineConfig, SyntheticLMPipeline
 from repro.runtime.fault_tolerance import StragglerMonitor, TrainLoopSupervisor
 from repro.train.steps import init_train_state, make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> None:
@@ -43,6 +44,7 @@ def main(argv=None) -> None:
     ap.add_argument("--inject-failure-at", type=int, default=-1,
                     help="simulate a crash at this step (tests restart path)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -49,9 +49,9 @@ Environment knobs
 ``REPRO_GUARD_VERIFY``    ``1`` always verify, ``0`` never; unset = only
                           while a fault plan is active.
 ``REPRO_GUARD_DEVICE``    key into ``VMEM_BUDGET_BYTES`` (e.g. ``tpu-v4``)
-                          for the preflight budget; unset = the most
-                          permissive budget, so preflight only rejects
-                          configs that no supported device could run.
+                          for the preflight budget; unset = the attached
+                          TPU's kind (an unknown TPU kind raises), or the
+                          most permissive budget when no TPU is attached.
 """
 
 from __future__ import annotations
@@ -130,11 +130,35 @@ def is_tracing(*values) -> bool:
     return any(isinstance(v, jax.core.Tracer) for v in values)
 
 
+# jax ``device_kind`` -> key of ``VMEM_BUDGET_BYTES``
+_TPU_KINDS = {
+    "TPU v3": "tpu-v3",
+    "TPU v4": "tpu-v4",
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v5": "tpu-v5p",
+    "TPU v5p": "tpu-v5p",
+}
+
+
+def _budget_device() -> str:
+    """Budget row for preflight: ``REPRO_GUARD_DEVICE``, else the attached
+    TPU's kind, else ``""`` (no TPU: kernels run interpreted)."""
+    device = os.environ.get("REPRO_GUARD_DEVICE", "")
+    if device or jax.default_backend() != "tpu":
+        return device
+    kind = jax.devices()[0].device_kind
+    if kind not in _TPU_KINDS:
+        raise ValueError(f"no VMEM budget known for TPU kind {kind!r}")
+    return _TPU_KINDS[kind]
+
+
 def _budget_bytes() -> int:
     from repro.analysis.checker import VMEM_BUDGET_BYTES, VMEM_USABLE_FRACTION
 
-    device = os.environ.get("REPRO_GUARD_DEVICE", "")
-    budget = VMEM_BUDGET_BYTES.get(device, max(VMEM_BUDGET_BYTES.values()))
+    device = _budget_device()
+    if device and device not in VMEM_BUDGET_BYTES:
+        raise ValueError(f"no VMEM budget row {device!r} (known: {sorted(VMEM_BUDGET_BYTES)})")
+    budget = VMEM_BUDGET_BYTES[device] if device else max(VMEM_BUDGET_BYTES.values())
     return int(budget * VMEM_USABLE_FRACTION)
 
 

@@ -52,6 +52,10 @@ from repro.telemetry import WALL, TickClock, get_telemetry
 from repro.train.steps import _cast
 from . import sampler as sampler_mod
 
+# jitted once: run eagerly, the sampler's merge-sort loops are traced and
+# compiled again on every call (minutes per token at a 32K vocabulary)
+_topk_sample = jax.jit(sampler_mod.topk_sample, static_argnames=("k",))
+
 
 @dataclasses.dataclass
 class Request:
@@ -198,7 +202,7 @@ class ServingEngine:
         if req.temperature <= 0:
             return int(sampler_mod.greedy(lrow)[0])
         self.key, sub = jax.random.split(self.key)
-        return int(sampler_mod.topk_sample(lrow, sub, k=req.topk, temperature=req.temperature)[0])
+        return int(_topk_sample(lrow, sub, k=req.topk, temperature=req.temperature)[0])
 
     def _tick_body(self) -> None:
         """Refill free slots, then one lockstep decode."""

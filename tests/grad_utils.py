@@ -5,7 +5,7 @@ Two complementary checks:
 * :func:`fd_check` — central finite differences in **float64** against
   the VJP of the same (pure-JAX) function.  Validates the *math* of a
   reference route; run it on oracle implementations, which execute fine
-  under ``jax.experimental.enable_x64``.
+  under ``jax.enable_x64``.
 * :func:`vjp_compare` — VJP-vs-VJP between the kernel route and the
   oracle route with an identical random cotangent.  The permutation
   VJPs are exact inverse gathers, so for them the comparison is
@@ -58,9 +58,7 @@ def fd_check(f, args, *, eps: float = 1e-5, rtol: float = 1e-6, atol: float = 1e
     float args are promoted to float64 (requires ``f`` be pure JAX —
     oracle routes, not Pallas calls).
     """
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         args64 = [
             jnp.asarray(np.asarray(a, np.float64)) if _is_float_leaf(a) else jnp.asarray(a)
             for a in args

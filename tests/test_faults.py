@@ -77,6 +77,26 @@ def _tree_equal(a, b) -> None:
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize(
+    "kind,row", [("TPU v5 lite", "tpu-v5e"), ("TPU v4", "tpu-v4"), ("TPU v9 imaginary", None)]
+)
+def test_budget_follows_attached_tpu_kind(monkeypatch, kind, row):
+    """Preflight budgets the attached TPU, and refuses a kind it cannot map."""
+    from repro.analysis.checker import VMEM_BUDGET_BYTES, VMEM_USABLE_FRACTION
+
+    class _Dev:
+        device_kind = kind
+
+    monkeypatch.delenv("REPRO_GUARD_DEVICE", raising=False)
+    monkeypatch.setattr(res.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(res.jax, "devices", lambda: [_Dev()])
+    if row is None:
+        with pytest.raises(ValueError, match="TPU kind"):
+            res._budget_bytes()
+    else:
+        assert res._budget_bytes() == int(VMEM_BUDGET_BYTES[row] * VMEM_USABLE_FRACTION)
+
+
 def test_plan_grammar():
     specs = faults.parse_plan(
         "launch:merge:0,2; nan:*:*; exchange:distributed_merge:1:window; vmem:sort*"

@@ -234,3 +234,36 @@ def test_int8_compression_bounded_error():
     comp, new_err = compression.compress_grads(g, err, "int8", 0.0)
     scale = float(jnp.max(jnp.abs(g["w"]))) / 127
     assert float(jnp.max(jnp.abs(comp["w"] - g["w"]))) <= scale * 0.5 + 1e-6
+
+
+# --- compile cache -----------------------------------------------------------
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.utils.compile_cache import enable_compile_cache
+d = enable_compile_cache()
+jax.jit(lambda x: jnp.sort(x) * 2)(jnp.arange(10.0)).block_until_ready()
+print(d)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, from_env):
+    """Compiled programs land in $JAX_COMPILATION_CACHE_DIR when it is set,
+    else in the checkout's fixed .jax_cache."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"), JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(root, ".jax_cache")
+    if from_env:
+        want = str(tmp_path / "cache")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env, capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == want
+    assert any(f.startswith("jit__lambda") for f in os.listdir(want))

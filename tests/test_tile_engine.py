@@ -264,15 +264,21 @@ def test_tune_autotune_updates_table():
 
 
 # ---------------------------------------------------------------------------
-# Interpret default (env-overridable, no call-site edits)
+# Interpret default (backend-derived, env override for tests)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("env,expected", [("0", "False"), ("false", "False"), ("1", "True"), (None, "True")])
+@pytest.mark.parametrize(
+    "env,expected",
+    [("0", "False"), ("false", "False"), ("1", "True"), (None, "True"), ("", "True")],
+)
 def test_interpret_env_default(env, expected):
-    code = "from repro.kernels import ops; print(ops.DEFAULT_INTERPRET)"
+    """Unset (or empty) the backend decides — the CPU here interprets; a
+    non-empty REPRO_PALLAS_INTERPRET overrides it either way."""
+    code = "from repro.kernels import ops; print(ops.default_interpret())"
     e = dict(os.environ)
     e["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    e["JAX_PLATFORMS"] = "cpu"
     e.pop("REPRO_PALLAS_INTERPRET", None)
     if env is not None:
         e["REPRO_PALLAS_INTERPRET"] = env
@@ -280,6 +286,19 @@ def test_interpret_env_default(env, expected):
         [sys.executable, "-c", code], env=e, capture_output=True, text=True, check=True
     )
     assert out.stdout.strip() == expected
+
+
+def test_interpret_default_follows_backend(monkeypatch):
+    """Decided per call, not at import: the same process compiles once the
+    backend is a TPU."""
+    from repro.kernels import merge_path as mpk
+
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(mpk.jax, "default_backend", lambda: "tpu")
+    assert mpk.default_interpret() is False
+    assert mpk._interp(True) is True
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert mpk.default_interpret() is True
 
 
 # ---------------------------------------------------------------------------

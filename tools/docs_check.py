@@ -53,7 +53,7 @@ def public_symbols() -> dict:
         and inspect.isfunction(obj)
         and obj.__module__ == "repro.kernels.ops"
     )
-    ops_names.append("DEFAULT_INTERPRET")  # the documented env-driven switch
+    ops_names.append("default_interpret")  # re-exported from kernels.merge_path
     return {
         "repro.core": sorted(core.__all__),
         "repro.kernels.ops": ops_names,

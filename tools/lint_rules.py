@@ -6,7 +6,8 @@ fix (the catalog with full rationale lives in ``docs/analysis.md``):
 
 * **L001** — no literal ``interpret=True`` / ``interpret=False`` at call
   sites.  The interpret default must route through
-  ``ops.DEFAULT_INTERPRET`` (the ``REPRO_PALLAS_INTERPRET`` env switch),
+  ``ops.default_interpret()`` (compiled on a TPU backend, interpreted
+  elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides it for tests),
   otherwise a hard-coded call site silently pins interpret mode on a
   real TPU — or compiled mode on the CPU CI box.
 * **L002** — no ``-x`` negation of keys to get descending order.  For
@@ -200,8 +201,8 @@ def lint_source(
                         vs.append(LintViolation(
                             "L001", path, line,
                             f"literal interpret={kw.value.value} at a call "
-                            f"site — route through ops.DEFAULT_INTERPRET "
-                            f"(REPRO_PALLAS_INTERPRET env) instead"))
+                            f"site — route through ops.default_interpret() "
+                            f"(backend-derived) instead"))
 
         # --- L002: -x key negation for descending order -------------------
         if isinstance(node, ast.Call) and _KEYED_CALL.search(_callee_name(node)):
