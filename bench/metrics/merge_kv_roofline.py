@@ -1,0 +1,7 @@
+"""merge_kv_roofline: the merge_kv calls' share of the HBM roofline (device trace)."""
+
+from harness.readers import op_roofline
+
+
+def read(rec):
+    return op_roofline(rec, "merge_kv")
