@@ -9,7 +9,10 @@ BENCH_JSON ?= $(shell $(PY) tools/bench_diff.py --next)
 
 .PHONY: test test-faults bench-smoke bench lint check ci docs-check train-smoke trace-smoke
 
-# tier-1 verify (ROADMAP.md)
+# tier-1 verify (ROADMAP.md). The gating run of this suite is
+# `pytest -n 6 --dist loadfile` under a 1,470 s limit: each test file goes
+# whole to one worker, so the longest file's total, not the sum over
+# files, sets the pace. Every test has a 300 s limit (tests/conftest.py).
 test:
 	PYTHONPATH=src $(PY) -m pytest -x -q
 

@@ -99,16 +99,16 @@ def test_shape_cell_applicability(arch):
 def test_sliding_window_decode_ring_buffer():
     """Decode with a ring-buffer cache matches full attention restricted to
     the window (hymba reduced config)."""
-    cfg = get_config("hymba-1.5b").reduced()
+    cfg = dataclasses.replace(get_config("hymba-1.5b").reduced(), sliding_window=4)
     params = init_params(cfg, jax.random.key(0))
-    n = 24  # > window (16) to exercise wraparound
+    n = 2 * cfg.sliding_window + 1  # the ring buffer wraps twice
     toks = jax.random.randint(jax.random.key(5), (B, n), 0, cfg.vocab_size)
     caches = init_caches(cfg, B, n)
+    assert caches["sub0"]["k"].shape[2] == cfg.sliding_window
+    decode = jax.jit(lambda caches, tok, pos: forward_decode(cfg, params, caches, tok, pos))
     logits = None
     for t in range(n):
-        logits, caches = forward_decode(
-            cfg, params, caches, toks[:, t : t + 1], jnp.full((B,), t, jnp.int32)
-        )
+        logits, caches = decode(caches, toks[:, t : t + 1], jnp.full((B,), t, jnp.int32))
     assert np.isfinite(np.asarray(logits)).all()
 
 

@@ -1,18 +1,10 @@
 """Context-parallel decode attention: shard_map combine == dense reference."""
 
-import os
-import subprocess
-import sys
-import textwrap
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from subproc import run_with_devices
 
 
 def test_context_parallel_matches_dense():
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    code = textwrap.dedent("""
+    run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp, math, functools
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core.distributed import shard_map  # jax-version compat wrapper
@@ -43,6 +35,3 @@ def test_context_parallel_matches_dense():
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
         print("ok")
     """)
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=560)
-    assert proc.returncode == 0, f"{proc.stdout}\n{proc.stderr}"

@@ -16,9 +16,6 @@ an env-driven ``REPRO_FAULTS`` plan (the ``env_plan`` test).
 """
 
 import os
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -31,8 +28,7 @@ from repro.kernels.ssm_scan import ssm_scan_pallas, ssm_scan_ref
 from repro.runtime import faults
 from repro.runtime import resilience as res
 from repro.runtime.resilience import FallbackWarning, GuardedDispatchError
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from subproc import run_with_devices
 
 # every injected fault emits a FallbackWarning by design; individual tests
 # assert on it explicitly with pytest.warns where the message matters
@@ -450,19 +446,6 @@ def test_serving_permanent_fault_never_wedges():
 # ---------------------------------------------------------------------------
 
 
-def run_with_devices(code: str, n: int = 8, timeout: int = 600) -> None:
-    # mirrors tests/test_distributed.py: fake device count in a subprocess
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("REPRO_FAULTS", None)  # the inline scripts inject their own plans
-    proc = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        env=env, capture_output=True, text=True, timeout=timeout,
-    )
-    assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
-
-
 def test_distributed_faults_multi_device():
     run_with_devices("""
         import warnings
@@ -474,8 +457,8 @@ def test_distributed_faults_multi_device():
         rng = np.random.default_rng(0)
 
         # merge: window exchange down -> gather serves, bit-identical
-        a = np.sort(rng.standard_normal(512)).astype(np.float32)
-        b = np.sort(rng.standard_normal(256)).astype(np.float32)
+        a = np.sort(rng.standard_normal(96)).astype(np.float32)
+        b = np.sort(rng.standard_normal(37)).astype(np.float32)
         clean = np.asarray(D.distributed_merge(jnp.array(a), jnp.array(b)))
         assert np.array_equal(clean, np.sort(np.concatenate([a, b])))
         res.reset_health()
@@ -493,7 +476,7 @@ def test_distributed_faults_multi_device():
 
         # sort: sampled splitters down -> capacity escalation (shape grows;
         # slice by the returned counts), still the exact global sort
-        x = rng.standard_normal(2048).astype(np.float32)
+        x = rng.standard_normal(256).astype(np.float32)
         res.reset_health()
         with faults.inject("launch:distributed_sort:*:sample"):
             s, cnt, ovf = D.distributed_sort(jnp.array(x))

@@ -36,6 +36,17 @@ F32_MAX = float(np.finfo(np.float32).max)
 VAL_POOL = np.array([-2.5, -1.0, 0.0, 0.5, 1.0, 1.5, F32_MAX], np.float32)
 
 
+# The oracle routes run jitted, like the kernel routes: eager dispatch
+# re-traces every loop of the merge-path sort on each call, while a jitted
+# route compiles once per shape and the fuzz below reuses a few shapes.
+_merge_sort = jax.jit(core.merge_sort)
+_merge_sort_kv = jax.jit(core.merge_sort_kv)
+_merge_sort_batched = jax.jit(core.merge_sort_batched)
+_merge_sort_kv_batched = jax.jit(core.merge_sort_kv_batched)
+_topk_batched = jax.jit(core.topk_batched, static_argnums=1)
+_topk_batched_ragged = jax.jit(core.topk_batched_ragged, static_argnums=1)
+
+
 def _pool_draw(rng, shape):
     return jnp.asarray(rng.choice(VAL_POOL, size=shape).astype(np.float32))
 
@@ -48,7 +59,7 @@ def _pool_draw(rng, shape):
 @pytest.mark.parametrize("seed,n", [(0, 2), (1, 7), (4, 33)])
 def test_sort_grad_bit_identical(seed, n):
     x = _pool_draw(np.random.default_rng(seed), (n,))
-    vjp_compare(lambda v: kops.sort(v), lambda v: core.merge_sort(v), [x], seed=seed)
+    vjp_compare(lambda v: kops.sort(v), lambda v: _merge_sort(v), [x], seed=seed)
 
 
 @pytest.mark.parametrize("seed,n", [(0, 5), (2, 29)])
@@ -58,7 +69,7 @@ def test_sort_kv_grads_bit_identical(seed, n):
     vals = jnp.asarray(rng.standard_normal(n), jnp.float32)
     vjp_compare(
         lambda k, v: kops.sort_kv(k, v),
-        lambda k, v: core.merge_sort_kv(k, v),
+        lambda k, v: _merge_sort_kv(k, v),
         [keys, vals],
         seed=seed,
     )
@@ -73,13 +84,13 @@ def test_sort_kv_int_payload_key_grads(seed, n):
 
     vjp_compare(
         lambda k: kops.sort_kv(k, vals)[0],
-        lambda k: core.merge_sort_kv(k, vals)[0],
+        lambda k: _merge_sort_kv(k, vals)[0],
         [keys],
         seed=seed,
     )
     np.testing.assert_array_equal(
         np.asarray(kops.sort_kv(keys, vals)[1]),
-        np.asarray(core.merge_sort_kv(keys, vals)[1]),
+        np.asarray(_merge_sort_kv(keys, vals)[1]),
     )
 
 
@@ -87,7 +98,7 @@ def test_sort_kv_int_payload_key_grads(seed, n):
 def test_sort_batched_grad_bit_identical(seed, b, n):
     x = _pool_draw(np.random.default_rng(seed), (b, n))
     vjp_compare(
-        lambda v: kops.sort_batched(v), lambda v: core.merge_sort_batched(v), [x],
+        lambda v: kops.sort_batched(v), lambda v: _merge_sort_batched(v), [x],
         seed=seed,
     )
 
@@ -99,7 +110,7 @@ def test_sort_kv_batched_grads_bit_identical(seed, b, n):
     vals = jnp.asarray(rng.standard_normal((b, n)), jnp.float32)
     vjp_compare(
         lambda k, v: kops.sort_kv_batched(k, v),
-        lambda k, v: core.merge_sort_kv_batched(k, v),
+        lambda k, v: _merge_sort_kv_batched(k, v),
         [keys, vals],
         seed=seed,
     )
@@ -110,7 +121,7 @@ def test_topk_batched_grad_bit_identical(seed, b, n, k):
     x = _pool_draw(np.random.default_rng(seed), (b, n))
     vjp_compare(
         lambda v: kops.topk_batched(v, k)[0],
-        lambda v: core.topk_batched(v, k)[0],
+        lambda v: _topk_batched(v, k)[0],
         [x],
         seed=seed,
     )
@@ -129,7 +140,7 @@ def test_topk_batched_ragged_grads(seed, b, n, k, lens):
     lens = jnp.asarray(lens, jnp.int32)
     g = vjp_compare(
         lambda v: kops.topk_batched_ragged(v, k, lens)[0],
-        lambda v: core.topk_batched_ragged(v, k, lens)[0],
+        lambda v: _topk_batched_ragged(v, k, lens)[0],
         [x],
         seed=seed,
     )
@@ -145,7 +156,7 @@ def test_sort_nonpow2_kernel_round_grad():
     x = jnp.asarray(rng.choice([-1.0, 0.0, 0.25, 1.0], size=192), jnp.float32)
     vjp_compare(
         lambda v: kops.sort(v, tile=128, leaf=32),
-        lambda v: core.merge_sort(v),
+        lambda v: _merge_sort(v),
         [x],
     )
 
@@ -157,7 +168,7 @@ def test_sort_kv_batched_kernel_round_grad():
     vals = jnp.asarray(rng.standard_normal((2, 192)), jnp.float32)
     vjp_compare(
         lambda k, v: kops.sort_kv_batched(k, v, tile=128, leaf=64),
-        lambda k, v: core.merge_sort_kv_batched(k, v),
+        lambda k, v: _merge_sort_kv_batched(k, v),
         [keys, vals],
     )
 
@@ -166,7 +177,7 @@ def test_sort_oracle_fd_check():
     """f64 central differences validate the oracle route the kernel is
     compared against (away from ties, where sort is differentiable)."""
     x = jnp.asarray([3.0, -1.5, 0.25, 7.0, -4.0, 2.0, 0.75, -0.5], jnp.float32)
-    fd_check(lambda v: core.merge_sort(v), [x], rtol=1e-6, atol=1e-9)
+    fd_check(lambda v: _merge_sort(v), [x], rtol=1e-6, atol=1e-9)
 
 
 def test_topk_oracle_fd_check():
@@ -192,8 +203,9 @@ def test_moe_dispatch_pallas_grads_match_oracle_route():
     def loss(p, xx, c):
         return jnp.sum(moe_mod.moe_apply(p, xx, c) ** 2)
 
-    (l_o, g_o) = jax.value_and_grad(loss, argnums=(0, 1))(params, x, cfg)
-    (l_k, g_k) = jax.value_and_grad(loss, argnums=(0, 1))(params, x, cfg_k)
+    grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)), static_argnums=2)
+    (l_o, g_o) = grad(params, x, cfg)
+    (l_k, g_k) = grad(params, x, cfg_k)
     assert float(l_o) == float(l_k)
     for lo, lk in zip(jax.tree.leaves(g_o), jax.tree.leaves(g_k)):
         np.testing.assert_array_equal(np.asarray(lo), np.asarray(lk))
@@ -210,22 +222,25 @@ if st is not None:
     def _farr(vals):
         return jnp.asarray(np.array(vals, np.float32))
 
+    # Lengths come from a few fixed sizes (tiny, non-pow2, past one tile)
+    # so examples share compiles; the values, the sentinel ties and the
+    # ragged lens are still drawn per example.
     @st.composite
-    def dup_keys(draw, min_n=2, max_n=48):
-        n = draw(st.integers(min_n, max_n))
+    def dup_keys(draw, sizes=(2, 7, 33, 48)):
+        n = draw(st.sampled_from(sizes))
         return _farr(draw(st.lists(key_vals, min_size=n, max_size=n)))
 
     @st.composite
-    def dup_keys_batched(draw, max_b=3, max_n=24):
-        b = draw(st.integers(1, max_b))
-        n = draw(st.integers(2, max_n))
+    def dup_keys_batched(draw, rows=(1, 3), sizes=(2, 9, 24)):
+        b = draw(st.sampled_from(rows))
+        n = draw(st.sampled_from(sizes))
         rows = [draw(st.lists(key_vals, min_size=n, max_size=n)) for _ in range(b)]
         return _farr(rows)
 
     @settings(max_examples=40)
     @given(dup_keys())
     def test_sort_grad_bit_identical_prop(x):
-        vjp_compare(lambda v: kops.sort(v), lambda v: core.merge_sort(v), [x])
+        vjp_compare(lambda v: kops.sort(v), lambda v: _merge_sort(v), [x])
 
     @settings(max_examples=30)
     @given(dup_keys())
@@ -234,7 +249,7 @@ if st is not None:
         vals = jnp.asarray(rng.standard_normal(keys.shape), jnp.float32)
         vjp_compare(
             lambda k, v: kops.sort_kv(k, v),
-            lambda k, v: core.merge_sort_kv(k, v),
+            lambda k, v: _merge_sort_kv(k, v),
             [keys, vals],
         )
 
@@ -245,12 +260,12 @@ if st is not None:
         vals = jnp.asarray(rng.standard_normal(keys.shape), jnp.float32)
         vjp_compare(
             lambda k, v: kops.sort_kv_batched(k, v),
-            lambda k, v: core.merge_sort_kv_batched(k, v),
+            lambda k, v: _merge_sort_kv_batched(k, v),
             [keys, vals],
         )
 
     @settings(max_examples=30)
-    @given(dup_keys_batched(), st.integers(1, 8), st.data())
+    @given(dup_keys_batched(), st.sampled_from([1, 3, 8]), st.data())
     def test_topk_batched_ragged_grads_prop(x, k, data):
         bsz, n = x.shape
         lens = jnp.asarray(
@@ -259,7 +274,7 @@ if st is not None:
         )
         g = vjp_compare(
             lambda v: kops.topk_batched_ragged(v, k, lens)[0],
-            lambda v: core.topk_batched_ragged(v, k, lens)[0],
+            lambda v: _topk_batched_ragged(v, k, lens)[0],
             [x],
         )
         dx = np.asarray(g[0])

@@ -24,6 +24,20 @@ from repro.core import (
 # bounded so int sentinels never collide with payloads
 ints = st.integers(min_value=-10_000, max_value=10_000)
 
+# The properties call jitted routes and draw lengths from a few fixed
+# sizes (empty, one, non-pow2, pow2, long), so examples share compiles
+# while the values are drawn per example.  Eager calls would re-trace the
+# merge-path loops on every example.
+_merge = jax.jit(merge)
+_diagonal_intersections = jax.jit(diagonal_intersections)
+_merge_sort = jax.jit(merge_sort)
+_stable_argsort = jax.jit(stable_argsort)
+_topk_desc = jax.jit(topk_desc, static_argnums=1)
+
+
+def fixed_len_lists(elems, sizes):
+    return st.sampled_from(sizes).flatmap(lambda n: st.lists(elems, min_size=n, max_size=n))
+
 
 def sorted_arr(draw, n, dtype=np.int32):
     xs = draw(st.lists(ints, min_size=n, max_size=n))
@@ -31,9 +45,9 @@ def sorted_arr(draw, n, dtype=np.int32):
 
 
 @st.composite
-def two_sorted(draw, max_n=200):
-    na = draw(st.integers(0, max_n))
-    nb = draw(st.integers(0, max_n))
+def two_sorted(draw, sizes=(0, 1, 7, 64, 200)):
+    na = draw(st.sampled_from(sizes))
+    nb = draw(st.sampled_from(sizes))
     return sorted_arr(draw, na), sorted_arr(draw, nb)
 
 
@@ -41,19 +55,19 @@ def two_sorted(draw, max_n=200):
 @given(two_sorted())
 def test_merge_is_stable_sorted_permutation(ab):
     a, b = ab
-    out = np.asarray(merge(jnp.array(a), jnp.array(b)))
+    out = np.asarray(_merge(jnp.array(a), jnp.array(b)))
     ref = np.sort(np.concatenate([a, b]), kind="stable")
     assert out.shape == (len(a) + len(b),)
     np.testing.assert_array_equal(out, ref)
 
 
 @settings(max_examples=40, deadline=None)
-@given(two_sorted(max_n=120), st.integers(0, 500))
+@given(two_sorted(sizes=(0, 1, 7, 64, 120)), st.integers(0, 500))
 def test_diagonal_intersection_invariants(ab, dseed):
     a, b = ab
     n = len(a) + len(b)
     d = np.array([dseed % (n + 1)]) if n else np.array([0])
-    ai = int(np.asarray(diagonal_intersections(jnp.array(a), jnp.array(b), jnp.array(d)))[0])
+    ai = int(np.asarray(_diagonal_intersections(jnp.array(a), jnp.array(b), jnp.array(d)))[0])
     bi = int(d[0]) - ai
     assert 0 <= ai <= len(a) and 0 <= bi <= len(b)
     # the partition is a valid merge-path point: everything consumed is <=
@@ -92,31 +106,31 @@ def test_segmented_merge_matches(data):
 
 
 @settings(max_examples=40, deadline=None)
-@given(st.lists(ints, min_size=0, max_size=500))
+@given(fixed_len_lists(ints, (0, 1, 7, 64, 257, 500)))
 def test_merge_sort(xs):
     x = np.array(xs, np.int32)
-    out = np.asarray(merge_sort(jnp.array(x)))
+    out = np.asarray(_merge_sort(jnp.array(x)))
     np.testing.assert_array_equal(out, np.sort(x))
 
 
 @settings(max_examples=40, deadline=None)
-@given(st.lists(st.integers(0, 7), min_size=1, max_size=300))
+@given(fixed_len_lists(st.integers(0, 7), (1, 7, 64, 300)))
 def test_stable_argsort_matches_numpy(keys):
     k = np.array(keys, np.int32)
-    perm = np.asarray(stable_argsort(jnp.array(k)))
+    perm = np.asarray(_stable_argsort(jnp.array(k)))
     np.testing.assert_array_equal(perm, np.argsort(k, kind="stable"))
 
 
 @settings(max_examples=40, deadline=None)
-@given(st.lists(st.floats(-1e6, 1e6, allow_nan=False, allow_subnormal=False, width=32),
-                min_size=1, max_size=200),
-       st.integers(1, 20))
+@given(fixed_len_lists(st.floats(-1e6, 1e6, allow_nan=False, allow_subnormal=False, width=32),
+                       (1, 7, 64, 200)),
+       st.sampled_from([1, 5, 20]))
 def test_topk_matches_lax(xs, k):
     # normalize -0.0 -> 0.0: lax.top_k uses IEEE total order (0.0 > -0.0)
     # while merge-path compares them equal and breaks ties by index.
     x = np.array(xs, np.float32) + 0.0
     k = min(k, len(x))
-    v, i = topk_desc(jnp.array(x), k)
+    v, i = _topk_desc(jnp.array(x), k)
     rv, ri = jax.lax.top_k(jnp.array(x), k)
     np.testing.assert_allclose(np.asarray(v), np.asarray(rv))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))

@@ -16,17 +16,29 @@ from repro.models.moe import _positions_cumsum, _positions_merge_path, capacity,
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.sampler import greedy, topk_sample, topp_sample
 
+# jitted once per shape: eager calls re-trace the merge-path loops each time
+_moe_apply = jax.jit(moe_apply, static_argnums=2)
+_topk_sample = jax.jit(topk_sample, static_argnames=("k",))
+_topp_sample = jax.jit(topp_sample, static_argnames=("k_max",))
+
 
 # --- MoE dispatch ------------------------------------------------------------
 
+_positions_mp = jax.jit(_positions_merge_path, static_argnums=1)
+_positions_cs = jax.jit(_positions_cumsum, static_argnums=1)
+
+
+# lengths from a few fixed sizes (one, non-pow2, pow2, long) so examples
+# share compiles; the expert ids are drawn per example
 @settings(max_examples=30, deadline=None)
-@given(st.lists(st.integers(0, 7), min_size=1, max_size=200))
+@given(st.sampled_from([1, 7, 64, 200]).flatmap(
+    lambda n: st.lists(st.integers(0, 7), min_size=n, max_size=n)))
 def test_merge_path_positions_match_cumsum(assignments):
     """The merge-path dispatch computes exactly the one-hot-cumsum
     position-in-expert (the O(N*E) baseline)."""
     flat = jnp.array(assignments, jnp.int32)
-    pos_mp = np.asarray(_positions_merge_path(flat, 8))
-    pos_cs = np.asarray(_positions_cumsum(flat, 8))
+    pos_mp = np.asarray(_positions_mp(flat, 8))
+    pos_cs = np.asarray(_positions_cs(flat, 8))
     np.testing.assert_array_equal(pos_mp, pos_cs)
 
 
@@ -38,7 +50,7 @@ def test_moe_conservation_no_drops():
     params = init_params(cfg, jax.random.key(0))
     layer0 = jax.tree.map(lambda t: t[0], params["layers"])
     x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model))
-    y = moe_apply(layer0["moe"], x, cfg)
+    y = _moe_apply(layer0["moe"], x, cfg)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
 
@@ -51,8 +63,8 @@ def test_moe_drop_determinism():
     params = init_params(cfg, jax.random.key(0))
     layer0 = jax.tree.map(lambda t: t[0], params["layers"])
     x = jax.random.normal(jax.random.key(1), (2, 32, cfg.d_model))
-    y1 = moe_apply(layer0["moe"], x, cfg)
-    y2 = moe_apply(layer0["moe"], x, cfg)
+    y1 = _moe_apply(layer0["moe"], x, cfg)
+    y2 = _moe_apply(layer0["moe"], x, cfg)
     np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
 
 
@@ -65,7 +77,7 @@ def test_moe_dispatch_modes_agree():
         cfg = dataclasses.replace(base, moe_dispatch=mode)
         params = init_params(cfg, jax.random.key(0))
         layer0 = jax.tree.map(lambda t: t[0], params["layers"])
-        outs[mode] = np.asarray(moe_apply(layer0["moe"], x, cfg))
+        outs[mode] = np.asarray(_moe_apply(layer0["moe"], x, cfg))
     np.testing.assert_allclose(outs["merge_path"], outs["cumsum"], rtol=1e-5, atol=1e-5)
 
 
@@ -89,7 +101,7 @@ def test_topk_sample_support():
     logits = jnp.asarray(np.random.default_rng(0).standard_normal((3, 64)), jnp.float32)
     topk_sets = [set(np.asarray(jax.lax.top_k(logits[i], 5)[1]).tolist()) for i in range(3)]
     for seed in range(20):
-        s = topk_sample(logits, jax.random.key(seed), k=5, temperature=1.0)
+        s = _topk_sample(logits, jax.random.key(seed), k=5, temperature=1.0)
         for i in range(3):
             assert int(s[i]) in topk_sets[i]
 
@@ -97,7 +109,7 @@ def test_topk_sample_support():
 def test_topp_always_keeps_best():
     logits = jnp.asarray([[10.0] + [0.0] * 63], jnp.float32)
     for seed in range(5):
-        s = topp_sample(logits, jax.random.key(seed), p=0.01, k_max=8)
+        s = _topp_sample(logits, jax.random.key(seed), p=0.01, k_max=8)
         assert int(s[0]) == 0
 
 

@@ -392,7 +392,7 @@ def test_moe_token_counts_padding_invariance():
 
     from repro.configs import get_config
     from repro.models import init_params
-    from repro.models.moe import moe_apply
+    from repro.models import moe as moe_mod
 
     base = get_config("phi3.5-moe-42b-a6.6b").reduced()
     cfg = dataclasses.replace(
@@ -401,6 +401,7 @@ def test_moe_token_counts_padding_invariance():
     params = init_params(cfg, jax.random.key(0))
     layer0 = jax.tree.map(lambda t: t[0], params["layers"])
     x = jax.random.normal(jax.random.key(1), (3, 32, cfg.d_model))
+    moe_apply = jax.jit(moe_mod.moe_apply, static_argnums=2)
     # full counts == no counts, bit-compatible
     y_full = moe_apply(layer0["moe"], x, cfg)
     y_cnt = moe_apply(layer0["moe"], x, cfg, token_counts=jnp.array([32, 32, 32]))
@@ -434,6 +435,7 @@ def test_sampler_empty_vocab_row_returns_minus_one():
     out-of-band marker); live rows are never contaminated."""
     from repro.serving.sampler import topk_sample
 
+    topk_sample = jax.jit(topk_sample, static_argnames=("k",))
     rng = np.random.default_rng(13)
     logits = rng.standard_normal((3, 64)).astype(np.float32)
     for seed in range(3):
@@ -447,6 +449,8 @@ def test_sampler_empty_vocab_row_returns_minus_one():
 def test_sampler_masked_vocab():
     from repro.serving.sampler import topk_sample, topp_sample
 
+    topk_sample = jax.jit(topk_sample, static_argnames=("k",))
+    topp_sample = jax.jit(topp_sample, static_argnames=("k_max",))
     rng = np.random.default_rng(11)
     logits = rng.standard_normal((4, 64)).astype(np.float32)
     lens = np.array([64, 30, 10, 5])

@@ -218,8 +218,10 @@ def test_compression_error_feedback_unbiased():
     err = compression.init_error_state(g)
     total_sent = jnp.zeros(1000)
     cur_err = err["w"]
+    # jitted as training runs it: one compile, not 50 eager dispatches
+    compress = jax.jit(lambda g, e: compression.compress_grads(g, e, "topk", 0.05))
     for _ in range(50):
-        comp, new_err = compression.compress_grads(g, {"w": cur_err}, "topk", 0.05)
+        comp, new_err = compress(g, {"w": cur_err})
         total_sent = total_sent + comp["w"]
         cur_err = new_err["w"]
     # cumulative transmitted + residual == cumulative gradient (exactness of EF)

@@ -3,7 +3,6 @@ convergence, and launch-layer plumbing that doesn't need 512 devices."""
 
 import subprocess
 import sys
-import os
 
 import numpy as np
 import pytest
@@ -13,8 +12,7 @@ import jax.numpy as jnp
 from repro.configs import SHAPES, TrainConfig, get_config, list_archs
 from repro.data.pipeline import PipelineConfig, SyntheticLMPipeline
 from repro.train.steps import init_train_state, make_train_step
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from subproc import SUBPROCESS_TIMEOUT_S, src_env
 
 
 def test_overfit_single_batch():
@@ -52,8 +50,7 @@ def test_train_driver_with_failure_injection(tmp_path):
         [sys.executable, "-m", "repro.launch.train", "--arch", "tinyllama-1.1b",
          "--steps", "25", "--batch", "2", "--seq", "16", "--ckpt-every", "10",
          "--inject-failure-at", "13", "--ckpt-dir", str(tmp_path)],
-        env={**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")},
-        capture_output=True, text=True, timeout=560,
+        env=src_env(), capture_output=True, text=True, timeout=SUBPROCESS_TIMEOUT_S,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "[recovery] restored step 10" in proc.stdout
@@ -64,8 +61,7 @@ def test_serve_driver(tmp_path):
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "tinyllama-1.1b",
          "--requests", "3", "--batch", "2", "--max-new", "3"],
-        env={**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")},
-        capture_output=True, text=True, timeout=560,
+        env=src_env(), capture_output=True, text=True, timeout=SUBPROCESS_TIMEOUT_S,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "served 3 requests" in proc.stdout

@@ -12,7 +12,6 @@ the (tile, leaf) autotune table, the env-overridable interpret default,
 and the consumer routes (MoE dispatch, sampler, distributed sort).
 """
 
-import os
 import subprocess
 import sys
 
@@ -31,8 +30,17 @@ from repro.kernels.merge_path import (
     merge_kv_pallas,
     merge_pallas,
 )
+from subproc import SUBPROCESS_TIMEOUT_S, jit_shard_map, src_env
 
 I32MAX = np.iinfo(np.int32).max
+
+# The core oracles run jitted: called eagerly, their loops are re-traced on
+# every call, which costs more than the interpreted kernels under test.
+_core_merge_batched = jax.jit(bat.merge_batched)
+_core_merge_batched_ragged = jax.jit(bat.merge_batched_ragged)
+_core_merge_kv_batched_ragged = jax.jit(bat.merge_kv_batched_ragged)
+_core_topk_batched = jax.jit(bat.topk_batched, static_argnums=1)
+_core_topk_batched_ragged = jax.jit(bat.topk_batched_ragged, static_argnums=1)
 
 
 def _eq(got, exp):
@@ -115,7 +123,7 @@ def test_fuzz_batched_hier_vs_matrix(seed, tile, leaf):
     h = merge_batched_pallas(a, b, tile=tile, leaf=leaf, engine="hier")
     m = merge_batched_pallas(a, b, tile=tile, leaf=leaf, engine="matrix")
     _eq(h, m)
-    _eq(h, bat.merge_batched(a, b))
+    _eq(h, _core_merge_batched(a, b))
 
 
 @pytest.mark.parametrize("seed,tile,leaf", [(0, 64, 16), (1, 128, 24), (2, 128, 100), (3, 256, 32)])
@@ -131,7 +139,7 @@ def test_fuzz_ragged_hier_vs_matrix(seed, tile, leaf):
     h = merge_batched_ragged_pallas(a, b, al, bl, tile=tile, leaf=leaf, engine="hier")
     m = merge_batched_ragged_pallas(a, b, al, bl, tile=tile, leaf=leaf, engine="matrix")
     _eq(h, m)
-    _eq(h, bat.merge_batched_ragged(a, b, al, bl))
+    _eq(h, _core_merge_batched_ragged(a, b, al, bl))
 
 
 @pytest.mark.parametrize("seed,tile,leaf", [(0, 64, 24), (1, 128, 32), (2, 128, 56)])
@@ -154,7 +162,7 @@ def test_fuzz_ragged_kv_sentinel_ties(seed, tile, leaf):
     km, vm = merge_kv_batched_ragged_pallas(*args, tile=tile, leaf=leaf, engine="matrix")
     _eq(kh, km)
     _eq(vh, vm)
-    rk, rv = bat.merge_kv_batched_ragged(*args)
+    rk, rv = _core_merge_kv_batched_ragged(*args)
     _eq(kh, rk)
     _eq(vh, rv)
 
@@ -223,7 +231,7 @@ def test_ops_topk_matches_core_and_lax():
     xi = jnp.asarray(rng.integers(-100, 100, (2, 640)).astype(np.int32))
     xi = xi.at[0, 0].set(np.iinfo(np.int32).min)
     vi, ii = ops.topk_batched(xi, 10, tile=64)
-    cv, ci = bat.topk_batched(xi, 10)
+    cv, ci = _core_topk_batched(xi, 10)
     _eq(vi, cv)
     _eq(ii, ci)
 
@@ -233,7 +241,7 @@ def test_ops_topk_ragged_matches_core():
     x = jnp.asarray(rng.standard_normal((4, 800)).astype(np.float32))
     lens = jnp.asarray([800, 500, 3, 0], jnp.int32)
     vals, idx = ops.topk_batched_ragged(x, 20, lens, tile=128)
-    cv, ci = bat.topk_batched_ragged(x, 20, lens)
+    cv, ci = _core_topk_batched_ragged(x, 20, lens)
     _eq(vals, cv)
     _eq(idx, ci)
 
@@ -268,24 +276,36 @@ def test_tune_autotune_updates_table():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize(
-    "env,expected",
-    [("0", "False"), ("false", "False"), ("1", "True"), (None, "True"), ("", "True")],
-)
-def test_interpret_env_default(env, expected):
+INTERPRET_ENVS = [("0", "False"), ("false", "False"), ("1", "True"), (None, "True"), ("", "True")]
+
+
+@pytest.fixture(scope="module")
+def interpret_defaults():
+    """``default_interpret()`` under each env value, from one fresh CPU child
+    (the env is read per call, so one import serves every case)."""
+    code = (
+        "import os\n"
+        "from repro.kernels import ops\n"
+        f"for env in {[env for env, _ in INTERPRET_ENVS]!r}:\n"
+        "    os.environ.pop('REPRO_PALLAS_INTERPRET', None)\n"
+        "    if env is not None:\n"
+        "        os.environ['REPRO_PALLAS_INTERPRET'] = env\n"
+        "    print(repr(env), ops.default_interpret())\n"
+    )
+    e = src_env(JAX_PLATFORMS="cpu")
+    e.pop("REPRO_PALLAS_INTERPRET", None)
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=e, capture_output=True, text=True, check=True,
+        timeout=SUBPROCESS_TIMEOUT_S,
+    )
+    return dict(line.rsplit(" ", 1) for line in out.stdout.strip().splitlines())
+
+
+@pytest.mark.parametrize("env,expected", INTERPRET_ENVS)
+def test_interpret_env_default(interpret_defaults, env, expected):
     """Unset (or empty) the backend decides — the CPU here interprets; a
     non-empty REPRO_PALLAS_INTERPRET overrides it either way."""
-    code = "from repro.kernels import ops; print(ops.default_interpret())"
-    e = dict(os.environ)
-    e["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    e["JAX_PLATFORMS"] = "cpu"
-    e.pop("REPRO_PALLAS_INTERPRET", None)
-    if env is not None:
-        e["REPRO_PALLAS_INTERPRET"] = env
-    out = subprocess.run(
-        [sys.executable, "-c", code], env=e, capture_output=True, text=True, check=True
-    )
-    assert out.stdout.strip() == expected
+    assert interpret_defaults[repr(env)] == expected
 
 
 def test_interpret_default_follows_backend(monkeypatch):
@@ -309,22 +329,26 @@ def test_interpret_default_follows_backend(monkeypatch):
 def test_moe_positions_pallas_backend_parity():
     from repro.models.moe import _positions_merge_path_batched
 
+    core = jax.jit(_positions_merge_path_batched, static_argnums=1)
     rng = np.random.default_rng(14)
     fe = jnp.asarray(rng.integers(0, 8, (3, 640)).astype(np.int32))
     _eq(
         _positions_merge_path_batched(fe, 8, None, "pallas"),
-        _positions_merge_path_batched(fe, 8),
+        core(fe, 8),
     )
     sl = jnp.asarray([640, 200, 0], jnp.int32)
     _eq(
         _positions_merge_path_batched(fe, 8, sl, "pallas"),
-        _positions_merge_path_batched(fe, 8, sl),
+        core(fe, 8, sl),
     )
 
 
 def test_sampler_pallas_backend_parity():
     from repro.serving.sampler import topk_sample, topp_sample
 
+    static = ("backend", "tile")
+    topk_sample = jax.jit(topk_sample, static_argnames=static)
+    topp_sample = jax.jit(topp_sample, static_argnames=static)
     rng = np.random.default_rng(15)
     logits = jnp.asarray(rng.standard_normal((3, 1024)).astype(np.float32))
     key = jax.random.key(21)
@@ -337,8 +361,13 @@ def test_sampler_pallas_backend_parity():
     _eq(topp_sample(logits, key, backend="pallas", tile=128), topp_sample(logits, key))
 
 
-def test_distributed_sort_pallas_local():
+def test_distributed_sort_pallas_local(monkeypatch):
+    from repro.core import distributed as dist
     from repro.core import distributed_sort
+
+    # compile the mapped body once instead of dispatching it per primitive;
+    # the guard around it still runs eagerly
+    monkeypatch.setattr(dist, "shard_map", jit_shard_map(dist.shard_map))
 
     rng = np.random.default_rng(16)
     x = jnp.asarray(rng.standard_normal(512).astype(np.float32))
