@@ -282,7 +282,7 @@ def bench_sort(rows: List[Dict], smoke: bool = False) -> None:
             label=f"sort/xla_baseline/n={n}",
         )
         # kernel-backed sort: wide rounds on the flat round kernel
-        # (hierarchical engine, autotuned (tile, leaf), padding hoisted)
+        # (bitonic tile engine, autotuned tile, padding hoisted)
         us_ko = timeit(
             kops.sort, x, iters=iters, warmup=warmup,
             label=f"sort/pallas_flat_rounds/n={n}",

@@ -1,13 +1,12 @@
-"""Tile-engine benchmark: (T, T) merge matrix vs the hierarchical engine.
+"""Tile-engine benchmark: (T, T) merge matrix vs the bitonic engine.
 
-The acceptance measurement for the two-level tile engine (PR 3): per
-tile size T, the same Pallas SPM kernel runs with
+Per tile size T, the same Pallas SPM kernel runs with
 
-* ``engine="matrix"`` — the original single-level body: a full (T, T)
-  merge matrix + (T, T) one-hot rank application, O(T^2) per tile;
-* ``engine="hier"``  — the two-level body: level-2 sub-diagonal
-  bisection into S-wide leaves, (S, S) leaf merge matrices, O(T) gather
-  apply — O(T*S + T log T) per tile.
+* ``engine="matrix"`` — the single-level body: a full (T, T) merge
+  matrix + (T, T) one-hot rank application, O(T^2) per tile;
+* ``engine="hier"``  — a stable bitonic merge of the two windows:
+  ``log2 T`` stages of elementwise compare-exchanges, O(T log T) per
+  tile (``leaf`` is passed and unused).
 
 Both engines produce bit-identical merges (asserted by
 ``tests/test_tile_engine.py``); this file records the speed gap for keys

@@ -402,8 +402,8 @@ def vmem_bytes(contract: KernelContract, cfg: LatticeConfig) -> int:
 
     Tile kernels: two input windows + output block(s) plus the engine
     working set — the ``(T, T)`` merge matrix and one-hot select for the
-    matrix engine (its defining cost), the ``(L, S, S)`` leaf stack and
-    O(T) gather temporaries for the hierarchical engine.  SSM scan: the
+    matrix engine (its defining cost), the bitonic network's element
+    arrays for the ``hier`` engine (``leaf`` does not enter).  SSM scan: the
     BlockSpec'd operands plus the scratch slabs, with the backward's
     ``(chunk+1) * d_tile * st`` recompute buffer dominating (the formula
     next to ``ssm_scan.bwd_hbm_bytes``).
@@ -427,7 +427,7 @@ def vmem_bytes(contract: KernelContract, cfg: LatticeConfig) -> int:
                + (s + 1) * d * st * f32      # recomputed chunk states
                + 2 * n_d * d * st * f32)     # g carry + dA accumulator slabs
         return max(fwd, bwd)
-    tile, leaf = cfg.tile, max(1, min(cfg.leaf, cfg.tile))
+    tile = cfg.tile
     v = _esize(VALUE_DTYPE) if contract.carries_values else 0
     io = 3 * tile * (e + v)  # two input windows + one output block (per operand)
     i32 = 4
@@ -436,10 +436,11 @@ def vmem_bytes(contract: KernelContract, cfg: LatticeConfig) -> int:
         # operand dtype (the widest where() intermediate dominates)
         work = tile * tile * (1 + max(e, v)) + 2 * tile * i32
     else:
-        nleaf = -(-tile // leaf)
-        # (L, S, S) bool leaf matrices + int32 rank sums, then O(T) ranks,
-        # alpha counts and gather indices
-        work = nleaf * leaf * leaf * (1 + i32) + 6 * tile * i32 + 2 * (tile + leaf) * (e + v)
+        # (key, int32 index[, value]) over the pow2-padded tile, held five
+        # times in a stage (the elements, both rotations, the partners, the
+        # result) + three int32-wide compare masks
+        p = 1 << max(0, (tile - 1).bit_length())
+        work = 5 * p * (e + i32 + v) + 3 * p * i32
     return io + work
 
 

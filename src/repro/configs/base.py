@@ -39,7 +39,7 @@ class ModelConfig:
     capacity_factor: float = 1.25
     shared_expert_ff: int = 0  # dense shared-expert MLP width (0 = none)
     # "merge_path" (fused pure-JAX batched sort) | "merge_path_pallas"
-    # (hierarchical tile engine, repro.kernels.ops) | "cumsum" (ablation)
+    # (bitonic tile engine, repro.kernels.ops) | "cumsum" (ablation)
     moe_dispatch: str = "merge_path"
 
     # --- SSM (mamba1) ---

@@ -52,7 +52,6 @@ __all__ = [
     "searchsorted_batched",
     "diagonal_intersections_batched",
     "diagonal_intersections_ragged",
-    "window_intersections",
     "merge_batched",
     "merge_kv_batched",
     "merge_batched_ragged",
@@ -176,53 +175,6 @@ def diagonal_intersections_ragged(
         mid = (lo + hi) >> 1
         av = jnp.take_along_axis(a, jnp.clip(mid, 0, na - 1), axis=1)
         bv = jnp.take_along_axis(b, jnp.clip(diags - 1 - mid, 0, nb - 1), axis=1)
-        pred = av <= bv  # A-priority: A[i] precedes B[j] iff A[i] <= B[j]
-        active = lo < hi
-        lo2 = jnp.where(active & pred, mid + 1, lo)
-        hi2 = jnp.where(active & ~pred, mid, hi)
-        return lo2, hi2
-
-    lo, hi = jax.lax.fori_loop(0, bisect_steps(min(na, nb)), body, (lo, hi))
-    return lo
-
-
-def window_intersections(
-    wa: jax.Array,
-    wb: jax.Array,
-    diags: jax.Array,
-    valid_a: jax.Array | None = None,
-    valid_b: jax.Array | None = None,
-) -> jax.Array:
-    """Algorithm 2 over two fixed-size sorted *windows* — kernel-traceable.
-
-    The shared bisection helper behind the hierarchical tile engine's
-    level-2 split (:mod:`repro.kernels.merge_path`): given two sorted
-    windows ``wa`` (Ta,) / ``wb`` (Tb,) and cross diagonals ``diags``
-    (D,), returns ``ai`` (D,) such that the first ``d`` outputs of the
-    stable A-priority merge of the windows are ``wa[:ai]`` and
-    ``wb[:d-ai]``.  Identical math to :func:`diagonal_intersections`, but
-
-    * operates on *values* (not refs), with a trip count fixed from the
-      static window sizes, so it traces inside a Pallas kernel body;
-    * optionally bounds the interval by traced scalar valid lengths
-      ``valid_a`` / ``valid_b`` (the windows' real-data prefixes) so no
-      probe ever compares against padding — callers must clamp ``diags``
-      to ``valid_a + valid_b`` first.
-    """
-    na, nb = wa.shape[0], wb.shape[0]
-    diags = jnp.asarray(diags, jnp.int32)
-    if valid_a is None:
-        lo = jnp.maximum(0, diags - nb)
-        hi = jnp.minimum(diags, na)
-    else:
-        lo = jnp.maximum(0, diags - valid_b)
-        hi = jnp.minimum(diags, valid_a)
-
-    def body(_, lh):
-        lo, hi = lh
-        mid = (lo + hi) >> 1
-        av = wa[jnp.clip(mid, 0, na - 1)]
-        bv = wb[jnp.clip(diags - 1 - mid, 0, nb - 1)]
         pred = av <= bv  # A-priority: A[i] precedes B[j] iff A[i] <= B[j]
         active = lo < hi
         lo2 = jnp.where(active & pred, mid + 1, lo)

@@ -746,8 +746,8 @@ def distributed_sort_local(
     on the Pallas ragged kernel (:func:`repro.kernels.ops.merge_k`) when
     ``local_sort="pallas"``, else :func:`repro.core.batched.merge_k`.
 
-    ``local_sort="pallas"`` runs the per-device sort on the hierarchical
-    tile engine (``repro.kernels.ops.sort``, autotuned ``(tile, leaf)``)
+    ``local_sort="pallas"`` runs the per-device sort on the bitonic tile
+    engine (``repro.kernels.ops.sort``, autotuned ``(tile, leaf)``)
     instead of the pure-JAX rounds — the local sort is the compute-bound
     stage of the sample sort, so it is the one worth a kernel.  The tiny
     splitter-candidate sort (``P*P`` elements) stays on the core path.

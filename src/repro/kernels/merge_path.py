@@ -27,26 +27,28 @@ shape equals the trailing dims of its array.
 
 Inside a tile, two engines are available (``engine=`` on every wrapper):
 
-* ``"hier"`` (default) — the **hierarchical two-level tile engine**.  The
-  paper's partition idea is applied *again inside the tile* (the
-  recursion Siebert & Träff's co-ranking makes explicit): a fixed-trip
-  vectorized bisection over the tile's sub-diagonals (level 2 of the
-  partition, :func:`_split`) cuts the T-output tile into ``ceil(T/S)``
-  leaves of ``S`` outputs each, and only the ``(S, S)`` leaf
-  materializes the paper's Merge Matrix to get cross-ranks.  Rank
-  application is an in-vreg lane gather driven by the leaf ranks (no
-  ``(T, T)`` one-hot).  Per-tile work drops from O(T^2) to
-  O(T*S + T log T); quadratic work only ever happens at the leaf size.
+* ``"hier"`` (default) — a **stable bitonic merge network** on whole
+  vregs (:func:`_hier_merge_window`).  Every element carries (key, source
+  index[, value]); the (key, index) order is exactly the stable
+  A-priority merge.  One compare-exchange of A against reversed B keeps
+  the T smallest of the 2T window elements as a bitonic sequence, and
+  ``log2(T)`` half-cleaner stages — lane rotations (``pltpu.roll``) for
+  strides under ``W``, sublane rotations above, a select on
+  ``p & stride`` each — sort it.  Work is O(T log T) elementwise vector
+  ops per tile, with no cross-lane reduction.  A tile that is not a power
+  of two is padded inside the network to the next one.  (The name stays
+  for the callers and the guard's ``pallas-hier`` edge; ``leaf=`` is
+  still accepted by every wrapper and does not shape the kernel.)
 * ``"matrix"`` — the single-level engine: materialize the full ``(T, T)``
   Merge Matrix and apply ranks via a ``(T, T)`` one-hot select.  Kept as
-  the bit-exactness oracle for the hierarchical engine and as the
-  benchmark baseline (``bench_tile_engine``).
+  the bit-exactness oracle for the bitonic engine and as the guard's
+  fallback edge.
 
-Both engines share the masked/unmasked rank form, so the ragged /
-key-value length-masking guarantees (pads excluded from ranks by *index*,
-never by comparing against the sentinel) carry through unchanged.  Every
-data movement inside a tile is a select or a gather of the elements'
-bit patterns, so results are bit-identical to the pure-JAX core routes.
+Both engines exclude pads by *index*, never by comparing against the
+sentinel, so the ragged / key-value length-masking guarantees hold for
+payload keys equal to the sentinel.  Every data movement inside a tile is
+a select of the elements' bit patterns, so results are bit-identical to
+the pure-JAX core routes.
 
 Output tiles are *exactly* T elements each (Corollary 7 — equal output
 partitions is the whole point of the path partition).
@@ -71,7 +73,7 @@ from repro.core.batched import (
     diagonal_intersections_batched,
     diagonal_intersections_ragged,
 )
-from repro.core.merge_path import bisect_steps, diagonal_intersections, max_sentinel
+from repro.core.merge_path import diagonal_intersections, max_sentinel
 
 DEFAULT_TILE = 512
 DEFAULT_LEAF = 32
@@ -119,12 +121,6 @@ def sort_tail(tile: int) -> int:
     return pl.cdiv(8 * _fetch_blocks(tile) * _lanes(tile), tile) * tile
 
 
-def _norm_leaf(tile: int, leaf: int) -> int:
-    """Clamp the leaf width into [1, min(tile, W)]: an S > T leaf is pure
-    waste, and a leaf window must fit one lane row to be gathered."""
-    return max(1, min(int(leaf), int(tile), _lanes(int(tile))))
-
-
 def _iota(shape, dim: int) -> jax.Array:
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
@@ -139,49 +135,6 @@ def _bits(x: jax.Array) -> jax.Array:
 def _unbits(x: jax.Array, dtype) -> jax.Array:
     dtype = jnp.dtype(dtype)
     return jax.lax.bitcast_convert_type(x, dtype) if x.dtype != dtype else x
-
-
-# ---------------------------------------------------------------------------
-# Merge-matrix ranks (shared by both engines)
-# ---------------------------------------------------------------------------
-
-
-def _leaf_ranks(
-    la: jax.Array,
-    lb: jax.Array,
-    valid_a: Optional[jax.Array] = None,
-    valid_b: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Cross-ranks of ``L`` stacked window pairs = their Merge Matrices, reduced.
-
-    ``la`` / ``lb`` are ``(L, S)``.  ``M[l, i, j] = (la[l, i] > lb[l, j])``
-    is the paper's binary merge matrix restricted to the pair; row sums
-    give how many B elements precede each A element, column sums of the
-    complement (ties go to A) the symmetric count; rank = own index +
-    cross count.  The hierarchical engine calls this on its ``(S, S)``
-    leaves (total work ``T*S``).
-
-    Unmasked, sentinel pads rank like real elements — exact for
-    **keys-only** tiles (a pad tied with a sentinel-valued payload writes
-    the same value).  ``valid_a`` / ``valid_b`` (``(L, 1)``) give the
-    number of real elements at the head of each window: pads are then
-    excluded from the cross counts by *index*, never by comparing against
-    the sentinel, so payload keys equal to the sentinel (real ``+inf``,
-    int ``iinfo.max``) rank exactly, and pad entries themselves rank
-    ``S`` (outside the window pair, dropped).
-    """
-    nl, s = la.shape
-    m = la[:, :, None] > lb[:, None, :]  # (L, S, S) merge matrices
-    iot = _iota((nl, s), 1)
-    if valid_a is None:
-        ra = iot + jnp.sum(m.astype(jnp.int32), axis=2)
-        rb = iot + jnp.sum((~m).astype(jnp.int32), axis=1)
-        return ra, rb
-    jvalid = _iota((nl, 1, s), 2) < valid_b[:, :, None]
-    ivalid = _iota((nl, s, 1), 1) < valid_a[:, :, None]
-    ra = iot + jnp.sum((m & jvalid).astype(jnp.int32), axis=2)
-    rb = iot + jnp.sum(((~m) & ivalid).astype(jnp.int32), axis=1)
-    return jnp.where(iot < valid_a, ra, s), jnp.where(iot < valid_b, rb, s)
 
 
 # ---------------------------------------------------------------------------
@@ -222,8 +175,10 @@ def _matrix_merge(wa2, wb2, *, wav2, wbv2, valid_a, valid_b, fill):
     The ``(T, T)`` merge matrix ``M[i, j] = A[i] > B[j]`` is built in
     ``R`` slabs of ``W`` rows (A as columns against the B row); row sums
     give A's ranks, column sums of the complement (ties go to A) B's.
-    With valid lengths, pads are excluded from the counts by index and
-    rank ``T`` (dropped) — the same rule as :func:`_leaf_ranks`.
+    Unmasked, sentinel pads rank like real elements — exact for keys-only
+    tiles.  With valid lengths, pads are excluded from the counts by index
+    and rank ``T`` (dropped), so payload keys equal to the sentinel rank
+    exactly.
     """
     r, w = wa2.shape
     t = r * w
@@ -259,156 +214,126 @@ def _matrix_merge(wa2, wb2, *, wav2, wbv2, valid_a, valid_b, fill):
 
 
 # ---------------------------------------------------------------------------
-# Hierarchical two-level tile engine
+# Bitonic tile engine ("hier")
 # ---------------------------------------------------------------------------
 #
-# Level 1 (host side): Alg. 2 over the *global* cross diagonals produces
-# per-tile (a_start, b_start) scalar-prefetch tables.  Level 2 (in-kernel):
-# Alg. 2 again, over the tile's own sub-diagonals (0, S, 2S, ...), splits
-# the T-output tile into leaves of S outputs — Lemma 16 applies
-# recursively, so leaf l needs at most S consecutive elements of each
-# window starting at its sub-partition point.  Only the (S, S) leaf
-# computes cross-ranks via the merge matrix; ranks are applied with an
-# in-vreg lane gather, so the T^2 term of the single-level engine becomes
-# T*S + T log T.
+# Level 1 (outside the kernel): Alg. 2 over the *global* cross diagonals
+# produces per-tile (a_start, b_start) scalar-prefetch tables, so a grid
+# step's T outputs are the T smallest of its two T-element windows.  Inside
+# the tile those T are picked and ordered by a bitonic merge on whole
+# vregs: every step is an elementwise compare-exchange between lanes or
+# sublanes a power of two apart (``pltpu.roll`` + select), with no
+# cross-lane reductions, gathers of ranks or quadratic work.
 
 
-def _probe(row: jax.Array, idx: jax.Array) -> jax.Array:
-    """``row[0, idx]`` for an ``(L, 1)`` index column (clamped into range):
-    a one-hot lane select + reduce, ``(L, T)`` work per probe."""
-    t = row.shape[1]
-    hit = _iota((idx.shape[0], t), 1) == jnp.clip(idx, 0, t - 1)
-    return jnp.sum(jnp.where(hit, row, jnp.zeros((), row.dtype)), axis=1, keepdims=True, dtype=row.dtype)
+def _pow2(n: int) -> int:
+    return 1 << max(0, (n - 1).bit_length())
 
 
-def _split(wa: jax.Array, wb: jax.Array, diags: jax.Array, valid_a=None, valid_b=None) -> jax.Array:
-    """Algorithm 2 over two sorted window rows ``(1, Ta)`` / ``(1, Tb)``.
-
-    For each sub-diagonal of the ``(L, 1)`` column ``diags`` returns how
-    many of the first ``d`` outputs of the stable A-priority merge of the
-    windows come from ``wa`` (an ``(L, 1)`` column).  The trip count is
-    fixed by the static window sizes; traced scalar valid lengths bound
-    the interval so no probe compares against padding (callers clamp
-    ``diags`` to ``valid_a + valid_b`` first).
-    """
-    ta, tb = wa.shape[1], wb.shape[1]
-    if valid_a is None:
-        lo = jnp.maximum(0, diags - tb)
-        hi = jnp.minimum(diags, ta)
-    else:
-        lo = jnp.maximum(0, diags - valid_b)
-        hi = jnp.minimum(diags, valid_a)
-
-    def body(_, lh):
-        lo, hi = lh
-        mid = (lo + hi) >> 1
-        pred = _probe(wa, mid) <= _probe(wb, diags - 1 - mid)  # A[i] precedes B[j] iff A[i] <= B[j]
-        active = lo < hi
-        return jnp.where(active & pred, mid + 1, lo), jnp.where(active & ~pred, mid, hi)
-
-    lo, _ = jax.lax.fori_loop(0, bisect_steps(min(ta, tb)), body, (lo, hi))
-    return lo
+def _flat_iota(shape) -> jax.Array:
+    """Row-major flat position of every slot of a 2-D ``shape``."""
+    return _iota(shape, 0) * shape[1] + _iota(shape, 1)
 
 
-def _leaf_windows(w2: jax.Array, start: jax.Array, s: int, fill) -> jax.Array:
-    """``(L, s)`` leaf windows of an ``(R, W)`` window (flat row-major):
-    row ``l`` holds ``w[start_l : start_l + s]``, ``fill`` past the end.
-
-    Each leaf spans at most two lane rows (``s <= W``): the two rows are
-    picked by a select over the ``R`` rows, then one in-vreg lane gather
-    rotates the leaf to the front.
-    """
-    r, w = w2.shape
-    nl = start.shape[0]
-    q, c = start // w, start % w
-    lane = _iota((nl, w), 1)
-    fillv = jnp.full((nl, w), fill, w2.dtype)
-    row0, row1 = fillv, fillv
-    for i in range(r):
-        row0 = jnp.where(q == i, w2[i : i + 1, :], row0)
-        row1 = jnp.where(q + 1 == i, w2[i : i + 1, :], row1)
-    wrap = c + lane >= w
-    idx = jnp.where(wrap, c + lane - w, c + lane)
-    g = jnp.where(
-        wrap,
-        jnp.take_along_axis(row1, idx, axis=1),
-        jnp.take_along_axis(row0, idx, axis=1),
-    )
-    g = jnp.where(start + lane < r * w, g, fillv)
-    return g[:, :s]
+def _pad_to(x: jax.Array, shape, fill) -> jax.Array:
+    """Grow an ``(R, W)`` window to ``shape`` (more lanes of one row, or
+    more rows), ``fill`` in the new slots."""
+    if x.shape == tuple(shape):
+        return x
+    axis = 1 if x.shape[0] == 1 else 0
+    extra = list(x.shape)
+    extra[axis] = shape[axis] - x.shape[axis]
+    return jnp.concatenate([x, jnp.full(extra, fill, x.dtype)], axis=axis)
 
 
-def _flatten_leaves(x: jax.Array, tile: int) -> jax.Array:
-    """``(L, S)`` leaf outputs -> the tile's ``(1, T)`` output row."""
-    return jnp.concatenate([x[l : l + 1, :] for l in range(x.shape[0])], axis=1)[:, :tile]
+def _reverse(x: jax.Array) -> jax.Array:
+    """Reverse the row-major flat order of a 2-D array: rows by static
+    slices, lanes by an in-vreg lane gather."""
+    r, w = x.shape
+    if r > 1:
+        x = jnp.concatenate([x[i : i + 1] for i in range(r - 1, -1, -1)], axis=0)
+    else:  # the TPU lane gather takes no single-row operand
+        x = jnp.broadcast_to(x, (8, w))
+    return jnp.take_along_axis(x, (w - 1) - _iota(x.shape, 1), axis=1)[:r]
 
 
-def _hier_merge_window(wa2, wb2, *, tile, leaf, wav2, wbv2, valid_a, valid_b, fill):
-    """Two-level merge of one tile's ``(R, W)`` windows → ``(1, T)`` rows.
+def _precedes(ka, ia, kb, ib) -> jax.Array:
+    """Element-wise (key, source index) order: keys compare in their own
+    dtype, ties go to the lower source index (A before B, earlier first)."""
+    return (ka < kb) | ((ka == kb) & (ia < ib))
 
-    1. **Level-2 split**: one fixed-trip vectorized bisection
-       (:func:`_split`) over the tile's sub-diagonals ``0, S, 2S, ...``
-       yields each leaf's sub-partition point ``(sa_l, sb_l)`` —
-       O((T/S) log T) probes.
-    2. **Leaf ranks**: the ``(S, S)`` merge matrix of every leaf window
-       pair, reduced to cross-ranks (masked when valid lengths are given)
-       — O(T*S) total, the only quadratic-in-anything step.
-    3. **Gather apply**: for output slot ``j`` of a leaf,
-       ``alpha[j] = |{i : ra[i] < j}|`` counts the A-side contributions
-       among the first ``j`` leaf outputs (``ra`` is strictly increasing,
-       so this is a rank lookup, computed leaf-locally); slot ``j`` is an
-       A output iff ``ra[alpha[j]] == j``, and the element is *gathered*
-       from ``la[alpha[j]]`` / ``lb[j - alpha[j]]``.
 
+def _half_clean(elems, s: int):
+    """One ascending bitonic half-cleaner stage of stride ``s`` (flat
+    positions) on the ``(key, index[, value])`` arrays: slot ``p`` keeps
+    the lesser of itself and ``p ^ s`` when ``p & s == 0``, else the
+    greater.  Partners come from two rotations along lanes (``s < W``) or
+    sublanes (``s >= W``)."""
+    r, w = elems[0].shape
+    axis, step = (1, s) if s < w else (0, s // w)
+    n = elems[0].shape[axis]
+    low = (_iota((r, w), axis) & step) == 0
+    mate = [jnp.where(low, pltpu.roll(x, n - step, axis), pltpu.roll(x, step, axis)) for x in elems]
+    keep = _precedes(elems[0], elems[1], mate[0], mate[1]) == low
+    return [jnp.where(keep, x, y) for x, y in zip(elems, mate)]
+
+
+def _hier_merge_window(wa2, wb2, *, wav2, wbv2, valid_a, valid_b, fill):
+    """Stable bitonic merge of one tile's ``(R, W)`` windows → ``(R, W)``
+    output blocks (keys, values | None).
+
+    1. **Elements**: each slot carries (key, source index[, value]); the
+       index is ``i`` for A slot ``i`` and ``P + j`` for B slot ``j``,
+       where ``P`` is the tile rounded up to a power of two (the network's
+       width; slots ``T..P`` are pads).  Slots at or past ``valid_a`` /
+       ``valid_b`` — and the round-up slots — are pads: sentinel key,
+       index ``2P`` higher, so they order after every real element, real
+       keys equal to the sentinel included.  The (key, index) order is
+       exactly the stable A-priority merge.
+    2. **Bitonic split**: A against reversed B, keeping the lesser of
+       each pair, leaves a bitonic sequence holding the ``P`` smallest.
+    3. **Sort**: ``log2(P)`` ascending half-cleaner stages; the first
+       ``T`` slots are the tile's outputs.
+
+    Unmasked (``valid_a`` None, keys-only), every window slot is real:
+    sentinel pads rank like keys, which is exact for keys alone.
     ``fill=True`` (ragged callers): slots past the windows' merged valid
     length get sentinel keys / zero values — bit-identical to the matrix
     engine's coverage fill.
     """
-    s = leaf
-    nleaf = -(-tile // s)  # ceil-div: last leaf may be short (trimmed below)
-    masked = valid_a is not None
+    r, w = wa2.shape
+    t = r * w
+    p = _pow2(t)
+    shape = (1, p) if r == 1 else (p // w, w)
+    pos = _flat_iota(shape)
     sent = max_sentinel(wa2.dtype)
-    diags = _iota((nleaf, 1), 0) * s
-    if masked:
-        total = valid_a + valid_b
-        diags = jnp.minimum(diags, total)
-    sa = _split(wa2.reshape(1, tile), wb2.reshape(1, tile), diags, valid_a, valid_b)
-    sb = diags - sa
-    la = _leaf_windows(wa2, sa, s, sent)
-    lb = _leaf_windows(wb2, sb, s, sent)
-    if masked:
-        va = jnp.clip(valid_a - sa, 0, s)  # (L, 1) valid prefix of each leaf window
-        vb = jnp.clip(valid_b - sb, 0, s)
-        ra, _ = _leaf_ranks(la, lb, va, vb)
-    else:
-        ra, _ = _leaf_ranks(la, lb)
-    # Clamp to S before the alpha count: a valid element belonging to a
-    # *later* leaf can rank past S, and pads rank exactly S — clamping
-    # keeps the per-leaf rank vector sorted without changing any count
-    # of ranks < j for j < S.
-    ra_c = jnp.minimum(ra, s)
-    jj = _iota((nleaf, s), 1)  # output slot within leaf
-    alpha = jnp.sum((ra_c[:, :, None] < _iota((1, 1, s), 2)).astype(jnp.int32), axis=1)
-    is_a = jnp.take_along_axis(ra_c, alpha, axis=1) == jj  # alpha[l, j] <= j < S: in bounds
-    src_b = jj - alpha
+    va = t if valid_a is None else valid_a
+    vb = t if valid_b is None else valid_b
 
-    def apply(xa, xb):
-        return _flatten_leaves(
-            jnp.where(
-                is_a,
-                jnp.take_along_axis(xa, alpha, axis=1),
-                jnp.take_along_axis(xb, src_b, axis=1),
-            ),
-            tile,
-        )
+    def side(k2, v2, valid, offset):
+        """One window's (key, index[, value]) arrays; B (offset P) reversed."""
+        xs = [_pad_to(k2, shape, sent)]
+        if v2 is not None:
+            xs.append(_pad_to(v2, shape, jnp.zeros((), v2.dtype)))
+        slot = pos
+        if offset:  # slot p holds B[P - 1 - p]
+            xs = [_reverse(x) for x in xs]
+            slot = (p - 1) - pos
+        real = slot < valid
+        return [jnp.where(real, xs[0], sent), offset + jnp.where(real, slot, slot + 2 * p), *xs[1:]]
 
-    out_k = apply(la, lb)
-    out_v = None
-    if wav2 is not None:
-        zero = jnp.zeros((), wav2.dtype)
-        out_v = apply(_leaf_windows(wav2, sa, s, zero), _leaf_windows(wbv2, sb, s, zero))
-    if masked and fill:
-        covered = _iota((1, tile), 1) < total
+    a = side(wa2, wav2, va, 0)
+    b = side(wb2, wbv2, vb, p)
+    first = _precedes(a[0], a[1], b[0], b[1])
+    elems = [jnp.where(first, x, y) for x, y in zip(a, b)]
+    s = p // 2
+    while s:
+        elems = _half_clean(elems, s)
+        s //= 2
+    out_k = elems[0][:r, :w]  # the first T slots, in the window's layout
+    out_v = None if wav2 is None else elems[2][:r, :w]
+    if valid_a is not None and fill:
+        covered = _flat_iota((r, w)) < valid_a + valid_b
         out_k = jnp.where(covered, out_k, sent)
         if out_v is not None:
             out_v = jnp.where(covered, out_v, jnp.zeros((), out_v.dtype))
@@ -419,8 +344,6 @@ def _tile_merge(
     wak: jax.Array,
     wbk: jax.Array,
     *,
-    tile: int,
-    leaf: int,
     engine: str,
     wav: Optional[jax.Array] = None,
     wbv: Optional[jax.Array] = None,
@@ -440,8 +363,7 @@ def _tile_merge(
     shape = wak.shape
     if engine == "hier":
         keys, vals = _hier_merge_window(
-            wak, wbk, tile=tile, leaf=leaf, wav2=wav, wbv2=wbv,
-            valid_a=valid_a, valid_b=valid_b, fill=fill,
+            wak, wbk, wav2=wav, wbv2=wbv, valid_a=valid_a, valid_b=valid_b, fill=fill,
         )
     elif engine == "matrix":
         keys, vals = _matrix_merge(
@@ -477,7 +399,7 @@ def _shift_window(x: jax.Array, c) -> jax.Array:
     return jnp.where(_iota((r, w), 1) < w - c, lo, hi)
 
 
-def _tile_kernel(*refs, n_prefetch, nd, nblk, geom, tile, leaf, engine, kv, fill):
+def _tile_kernel(*refs, n_prefetch, nd, nblk, geom, tile, engine, kv, fill):
     """One ``T``-output grid step: select the windows, merge, write the block.
 
     Operand order: ``n_prefetch`` scalar-prefetch tables, ``nblk`` aligned
@@ -504,7 +426,7 @@ def _tile_kernel(*refs, n_prefetch, nd, nblk, geom, tile, leaf, engine, kv, fill
                                 (start // w) % 8, tile // w + 1)
             win.append(_shift_window(rows, start % w))
         keys, vals = _tile_merge(
-            win[0], win[1], tile=tile, leaf=leaf, engine=engine,
+            win[0], win[1], engine=engine,
             wav=win[2] if kv else None, wbv=win[3] if kv else None,
             valid_a=valid_a, valid_b=valid_b, fill=fill,
         )
@@ -536,7 +458,7 @@ def _rows(x: jax.Array, tile: int, fill) -> jax.Array:
     return jnp.concatenate([x, pad], axis=-1).reshape(x.shape[:-1] + (rows, w))
 
 
-def _launch(geom, grid, tables, ins, *, tile, leaf, engine, fill, interpret):
+def _launch(geom, grid, tables, ins, *, tile, engine, fill, interpret):
     """``pallas_call`` of :func:`_tile_kernel` over ``grid``; returns the
     output(s) as ``grid + (T,)`` arrays (one T-slab per grid step)."""
     w = _lanes(tile)
@@ -558,7 +480,7 @@ def _launch(geom, grid, tables, ins, *, tile, leaf, engine, fill, interpret):
     out = pl.pallas_call(
         functools.partial(
             _tile_kernel, n_prefetch=len(tables), nd=nd, nblk=nblk, geom=geom, tile=tile,
-            leaf=_norm_leaf(tile, leaf), engine=engine, kv=kv, fill=fill,
+            engine=engine, kv=kv, fill=fill,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
@@ -606,7 +528,7 @@ def _geom_1d(na=None, nb=None, tile=None):
         a0, b0 = a_starts[ids[0]], b_starts[ids[0]]
         if na is None:
             return None, a0, b0, None, None, None
-        # Length-masked ranks: a window pad tied with a real sentinel-valued
+        # Valid lengths: a window pad tied with a real sentinel-valued
         # key must not steal its slot and surface a zero value.
         return None, a0, b0, jnp.clip(na - a0, 0, tile), jnp.clip(nb - b0, 0, tile), None
 
@@ -622,11 +544,15 @@ def merge_pallas(
     engine: str = DEFAULT_ENGINE,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Merge two sorted 1-D arrays with the Pallas SPM kernel."""
+    """Merge two sorted 1-D arrays with the Pallas SPM kernel.
+
+    ``leaf`` is accepted by every wrapper here for its callers and the
+    kernel contract; the tile engines do not use it.
+    """
     ap, bp, a_starts, b_starts, n, nt = _prepare(a, b, tile)
     (out,) = _launch(
         _geom_1d(), (nt,), (a_starts, b_starts), (ap, bp),
-        tile=tile, leaf=leaf, engine=engine, fill=False, interpret=interpret,
+        tile=tile, engine=engine, fill=False, interpret=interpret,
     )
     return out.reshape(-1)[:n]
 
@@ -650,7 +576,7 @@ def merge_kv_pallas(
     ko, vo = _launch(
         _geom_1d(ak.shape[0], bk.shape[0], tile), (nt,), (a_starts, b_starts),
         (akp, bkp, _rows(av.astype(vd), tile, zero), _rows(bv.astype(vd), tile, zero)),
-        tile=tile, leaf=leaf, engine=engine, fill=False, interpret=interpret,
+        tile=tile, engine=engine, fill=False, interpret=interpret,
     )
     return ko.reshape(-1)[:n], vo.reshape(-1)[:n]
 
@@ -732,7 +658,7 @@ def merge_batched_pallas(
     ap, bp, a_starts, b_starts, bsz, n, nt = _prepare_batched(a, b, tile)
     (out,) = _launch(
         _geom_batched(), (bsz, nt), (a_starts, b_starts), (ap, bp),
-        tile=tile, leaf=leaf, engine=engine, fill=False, interpret=interpret,
+        tile=tile, engine=engine, fill=False, interpret=interpret,
     )
     return out.reshape(bsz, -1)[:, :n]
 
@@ -760,7 +686,7 @@ def merge_kv_batched_pallas(
     ko, vo = _launch(
         _geom_batched(ak.shape[1], bk.shape[1], tile), (bsz, nt), (a_starts, b_starts),
         (akp, bkp, _rows(av.astype(vd), tile, zero), _rows(bv.astype(vd), tile, zero)),
-        tile=tile, leaf=leaf, engine=engine, fill=False, interpret=interpret,
+        tile=tile, engine=engine, fill=False, interpret=interpret,
     )
     return ko.reshape(bsz, -1)[:, :n], vo.reshape(bsz, -1)[:, :n]
 
@@ -772,9 +698,8 @@ def merge_kv_batched_pallas(
 # The ragged form is the batched kernel with one addition: alongside the
 # (B, nt) start tables, the per-row valid lengths ride in as scalar-
 # prefetch operands (SMEM).  Each (batch, tile) grid step derives its
-# windows' valid prefixes from the length tables and uses the length-
-# masked rank form (at leaf scale for the hierarchical engine), so
-# padding never shadows a payload and output slots past a row's merged
+# windows' valid prefixes from the length tables, and both engines
+# exclude slots past them by index, so padding never shadows a payload and output slots past a row's merged
 # length are filled with the sentinel.  The partition phase clamps every
 # row's diagonals to that row's total valid length, so short rows simply
 # run out of work early (their trailing tiles write pure sentinel
@@ -830,7 +755,7 @@ def merge_batched_ragged_pallas(
     ap, bp, tables, bsz, n, nt = _prepare_batched_ragged(a, b, a_lens, b_lens, tile)
     (out,) = _launch(
         _geom_batched(tile=tile, ragged=True), (bsz, nt), tables, (ap, bp),
-        tile=tile, leaf=leaf, engine=engine, fill=True, interpret=interpret,
+        tile=tile, engine=engine, fill=True, interpret=interpret,
     )
     return out.reshape(bsz, -1)[:, :n]
 
@@ -860,7 +785,7 @@ def merge_kv_batched_ragged_pallas(
     ko, vo = _launch(
         _geom_batched(tile=tile, ragged=True), (bsz, nt), tables,
         (akp, bkp, _rows(av.astype(vd), tile, zero), _rows(bv.astype(vd), tile, zero)),
-        tile=tile, leaf=leaf, engine=engine, fill=True, interpret=interpret,
+        tile=tile, engine=engine, fill=True, interpret=interpret,
     )
     return ko.reshape(bsz, -1)[:, :n], vo.reshape(bsz, -1)[:, :n]
 
@@ -873,7 +798,7 @@ def merge_kv_batched_ragged_pallas(
 # = pow2-padded data, then a sentinel tail built once per sort), run pairs
 # are addressed by *flat* offsets riding in as scalar-prefetch tables, and
 # window overrun into a neighboring run is excluded by the length-masked
-# rank form (valid counts derived in-kernel from the static run width)
+# windows (valid counts derived in-kernel from the static run width)
 # instead of by padding.  The sentinel tail of the output buffer is
 # re-written by trailing grid steps, so the buffer never round-trips
 # through a host-side concatenate between rounds.  (The tail is longer
@@ -902,7 +827,7 @@ def _geom_sort(width, tile, tpp, n_data):
         s_id = ids[0]
         base = (s_id // tpp) * (2 * width)
         a0, b0 = fa[s_id], fb[s_id]
-        # masked ranks: overrun past a run's width reads the *neighbor*
+        # valid lengths: overrun past a run's width reads the *neighbor*
         # run (flat layout) — excluded by index, exactly like padding
         valid_a = jnp.clip(width - (a0 - base), 0, tile)
         valid_b = jnp.clip(width - (b0 - base - width), 0, tile)
@@ -911,7 +836,7 @@ def _geom_sort(width, tile, tpp, n_data):
     return geom
 
 
-def _sort_round(bufs, width, tile, leaf, engine, interpret):
+def _sort_round(bufs, width, tile, engine, interpret):
     ntail = sort_tail(tile) // tile
     m = bufs[0].shape[0] - ntail * tile
     fa, fb, ndata, tpp = _sort_round_starts(bufs[0], m, width, tile, ntail)
@@ -920,7 +845,7 @@ def _sort_round(bufs, width, tile, leaf, engine, interpret):
     ins = tuple(x.reshape(-1, w) for x in bufs for _ in (0, 1))
     out = _launch(
         _geom_sort(width, tile, tpp, ndata), (ndata + ntail,), (fa, fb), ins,
-        tile=tile, leaf=leaf, engine=engine, fill=False, interpret=interpret,
+        tile=tile, engine=engine, fill=False, interpret=interpret,
     )
     return [o.reshape(-1) for o in out]
 
@@ -942,7 +867,7 @@ def sort_round_pallas(
     Returns the same layout with runs of ``2 * width`` — call repeatedly
     to sort.
     """
-    (out,) = _sort_round([xf], width, tile, leaf, engine, interpret)
+    (out,) = _sort_round([xf], width, tile, engine, interpret)
     return out
 
 
@@ -957,5 +882,5 @@ def sort_round_kv_pallas(
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Key-value :func:`sort_round_pallas` (values: zero-filled tail)."""
-    ko, vo = _sort_round([kf, vf], width, tile, leaf, engine, interpret)
+    ko, vo = _sort_round([kf, vf], width, tile, engine, interpret)
     return ko, vo

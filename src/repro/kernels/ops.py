@@ -26,10 +26,10 @@ the primary attempt directly — Python cannot branch on device failures
 inside a trace.  See ``docs/robustness.md``.
 
 **Tile/leaf selection**: every wrapper takes ``tile=None`` / ``leaf=None``
-and resolves them through :func:`repro.kernels.tune.pick` (the
-micro-bench table of the hierarchical tile engine), so consumers that
-don't care get measured defaults and consumers that do (serving sampler,
-MoE dispatch, distributed sort) can pass their own.
+and resolves them through :func:`repro.kernels.tune.pick` (a micro-bench
+table), so consumers that don't care get measured defaults and consumers
+that do (serving sampler, MoE dispatch, distributed sort) can pass their
+own.  ``leaf`` is resolved and checked but no longer shapes the kernel.
 
 **Interpret default**: ``interpret=None`` (the default everywhere)
 resolves through :func:`default_interpret` when the call is made:
@@ -981,7 +981,7 @@ def merge_k(
     ``(k * n,)`` merged valid prefix followed by sentinel padding — a
     traced ``lens`` forbids trimming further).  Each of the
     ``ceil(log2 k)`` tournament rounds is one :func:`merge_batched_ragged`
-    call, i.e. the hierarchical tile engine once the runs are wide enough
+    call, i.e. the bitonic tile engine once the runs are wide enough
     to tile — this is ``distributed_sort``'s bucket combine for
     ``local_sort="pallas", combine="tournament"``.
 

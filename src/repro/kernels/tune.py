@@ -1,9 +1,9 @@
-"""(tile, leaf) selection for the hierarchical tile engine.
+"""(tile, leaf) selection for the merge-path kernels.
 
-The two-level engine has two knobs: the output tile ``T`` (level-1
-partition / VMEM working set) and the leaf width ``S`` (the only scale at
-which quadratic merge-matrix work happens).  The sweet spot depends on
-dtype and problem size, so ``kernels.ops`` resolves unspecified
+The output tile ``T`` sets the partition's grain, the VMEM working set
+and the width of the bitonic network that merges a tile.  The leaf width
+``S`` is still resolved and passed, for the callers and the kernel
+contract; no engine uses it.  ``kernels.ops`` resolves unspecified
 ``tile=None`` / ``leaf=None`` arguments through :func:`pick`, which
 consults a small micro-bench table:
 
@@ -120,7 +120,7 @@ def autotune(
     update_table: bool = True,
 ) -> Tuple[int, int]:
     """Measure the candidate ``(tile, leaf)`` grid on an ``n``-element
-    hierarchical merge and return the fastest pair.
+    merge and return the fastest pair.
 
     The micro-bench is the keys-only 1-D merge (the kv and batched
     variants share the same tile body, so the optimum transfers).  With

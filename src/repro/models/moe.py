@@ -97,7 +97,7 @@ def _positions_merge_path_batched(
     test drops them with no extra mask.
 
     ``backend="pallas"`` (``moe_dispatch="merge_path_pallas"``) routes the
-    routing sort through the hierarchical tile engine
+    routing sort through the bitonic tile engine
     (``repro.kernels.ops.sort_kv_batched``, autotuned ``(tile, leaf)``)
     — same stable-sort contract, wide rows ride the flat round kernel.
     The ragged form masks the expert keys to the sentinel first, exactly

@@ -31,7 +31,7 @@ negative indexing would silently wrap to the last vocab entry).  Rows
 with ``vocab_lens[r] >= 1`` always return a valid in-prefix id.
 
 **Backend** (``backend="pallas"``): the candidate sort runs on the
-hierarchical tile engine (``repro.kernels.ops.topk_batched{,_ragged}``)
+bitonic tile engine (``repro.kernels.ops.topk_batched{,_ragged}``)
 instead of the fused pure-JAX path — same stable contract and the same
 ragged semantics, with ``(tile, leaf)`` either passed explicitly or
 resolved from the autotune table (``repro.kernels.tune``).  Production

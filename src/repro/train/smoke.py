@@ -8,7 +8,7 @@ training through the Pallas kernels:
   AND backward go through ``repro.kernels.ssm_scan``'s chunk-recompute
   ``custom_vjp``;
 * phi3.5-moe (MoE family) with ``moe_dispatch="merge_path_pallas"`` —
-  dispatch positions come from the hierarchical tile-engine kv-sort in
+  dispatch positions come from the bitonic tile-engine kv-sort in
   ``repro.kernels.ops`` (seq is sized so the flat round actually exceeds
   the minimum Pallas tile and the kernel, not the XLA fallback, runs).
 

@@ -1,11 +1,11 @@
-"""Hierarchical two-level tile engine: bit-exactness + satellite coverage.
+"""Default tile engine: bit-exactness + satellite coverage.
 
-The property the whole PR rests on: for every kernel variant, the
-hierarchical engine (level-2 sub-diagonal bisection + (S, S) leaf merge
-matrices + O(T) gather apply) produces output **bit-identical** to the
-single-level (T, T) merge-matrix engine — over fuzzed windows with
-duplicates, payload keys tied with the sentinel (``+inf`` /
-``iinfo.max``), ragged valid lengths, and non-divisible T/S combos.
+For every kernel variant, ``engine="hier"`` (a stable bitonic merge of
+each tile) produces output **bit-identical** to the single-level (T, T)
+merge-matrix engine — over fuzzed windows with duplicates, payload keys
+tied with the sentinel (``+inf`` / ``iinfo.max``), ragged valid lengths,
+and tiles that are not powers of two (the ``leaf`` values below no longer
+shape the kernel; they are passed as callers pass them).
 
 Also covered: the flat sort rounds (padding hoisted out of the loop),
 the (tile, leaf) autotune table, the env-overridable interpret default,

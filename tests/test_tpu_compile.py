@@ -27,6 +27,8 @@ from repro.kernels.ssm_scan import ssm_scan_pallas
 I32, F32 = jnp.int32, jnp.float32
 # falcon-mamba-7b's scan: (B, L, d_inner, state)
 SSM = (1, 2048, 8192, 16)
+# TPC-H SF10: orders and lineitem rows
+ORDERS, LINEITEM = 15_000_000, 59_986_052
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +89,15 @@ CASES = {
     "sort_round_kv_2^24": lambda spec: _compile(
         lambda k, v: mpk.sort_round_kv_pallas(k, v, 1 << 12, tile=512, leaf=32, interpret=False),
         spec(((1 << 24) + mpk.sort_tail(512),), F32), spec(((1 << 24) + mpk.sort_tail(512),), I32),
+    ),
+    # the TPC-H SF10 benchmark cells: ORDER BY l_orderkey, orders JOIN lineitem
+    "sort_kv_i32_tpch_sf10": lambda spec: _compile(
+        lambda k, v: ops.sort_kv(k, v, tile=1024, interpret=False),
+        spec((LINEITEM,), I32), spec((LINEITEM,), I32),
+    ),
+    "merge_kv_i32_tpch_sf10": lambda spec: _compile(
+        lambda a, av, b, bv: ops.merge_kv(a, av, b, bv, interpret=False),
+        spec((ORDERS,), I32), spec((ORDERS,), I32), spec((LINEITEM,), I32), spec((LINEITEM,), I32),
     ),
     "topk_batched_64x32064": lambda spec: _compile(
         lambda x: ops.topk_batched(x, 40, interpret=False), spec((64, 32064), F32)
