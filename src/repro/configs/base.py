@@ -41,6 +41,22 @@ class ModelConfig:
     # "merge_path" (fused pure-JAX batched sort) | "merge_path_pallas"
     # (bitonic tile engine, repro.kernels.ops) | "cumsum" (ablation)
     moe_dispatch: str = "merge_path"
+    # "softmax": top-k of the softmax probabilities, renormalised.
+    # "sigmoid": DeepSeek-V3 noaux_tc -- independent sigmoid scores, the
+    # top-k chosen on score + a per-expert correction bias, weighted by the
+    # unbiased scores renormalised and times ``routed_scaling``
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    # leading dense SwiGLU layers (DeepSeek-V3 first_k_dense_replace) before
+    # the uniform expert layers, and their width
+    first_k_dense: int = 0
+    dense_ff: int = 0
+
+    # --- multi-head latent attention (MLA; kv_lora_rank 0 = none) ---
+    kv_lora_rank: int = 0  # the latent c a cache holds per position
+    qk_nope_head_dim: int = 0  # per-head query/key width without RoPE
+    qk_rope_head_dim: int = 0  # RoPE width; one key part shared by every head
+    v_head_dim: int = 0
 
     # --- SSM (mamba1) ---
     ssm_state: int = 0
@@ -81,6 +97,15 @@ class ModelConfig:
     subquadratic: bool = False
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def stack_layers(self) -> int:
+        """Layers in the scanned uniform stack: all but the leading dense ones."""
+        return self.num_layers - self.first_k_dense
+
+    @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.num_heads)
 
@@ -101,6 +126,15 @@ class ModelConfig:
         """Scan unit: layers are scanned in homogeneous groups."""
         return self.global_every if self.global_every > 0 else 1
 
+    def attn_params(self) -> int:
+        """Parameters of one layer's self-attention."""
+        d, h = self.d_model, self.num_heads
+        if self.is_mla:
+            r, nope, rope, v = self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+            return d * h * (nope + rope) + d * (r + rope) + r + r * h * (nope + v) + h * v * d
+        hd = self.resolved_head_dim
+        return d * (h + 2 * self.num_kv_heads) * hd + h * hd * d
+
     def n_params(self) -> int:
         """Analytic parameter count (embeddings included once if tied)."""
         d, hd = self.d_model, self.resolved_head_dim
@@ -109,8 +143,7 @@ class ModelConfig:
             n += self.vocab_size * d
         per_layer = 0
         if self.family != SSM:
-            per_layer += d * (self.num_heads + 2 * self.num_kv_heads) * hd
-            per_layer += self.num_heads * hd * d
+            per_layer += self.attn_params()
         if self.family in (SSM, HYBRID):
             di, st = self.d_inner, self.ssm_state
             per_layer += d * 2 * di + di * self.ssm_conv
@@ -119,6 +152,8 @@ class ModelConfig:
         if self.num_experts:
             e, fe = self.num_experts, self.d_ff
             per_layer += d * e  # router
+            if self.router_scoring == "sigmoid":
+                per_layer += e  # correction bias
             per_layer += e * (3 * d * fe)
             if self.shared_expert_ff:
                 per_layer += 3 * d * self.shared_expert_ff
@@ -126,7 +161,8 @@ class ModelConfig:
             mult = 3 if self.act in ("silu", "gelu_gated") else 2  # gated adds wg
             per_layer += mult * d * self.d_ff
         per_layer += 2 * d  # norms
-        n += self.num_layers * per_layer
+        n += self.stack_layers * per_layer
+        n += self.first_k_dense * (self.attn_params() + 3 * d * self.dense_ff + 2 * d)
         if self.encoder_layers:
             enc = d * (self.num_heads + 2 * self.num_kv_heads) * hd + self.num_heads * hd * d
             enc += (3 if self.act in ("silu", "gelu_gated") else 2) * d * self.d_ff + 2 * d
@@ -141,7 +177,7 @@ class ModelConfig:
         if not self.num_experts:
             return self.n_params()
         d, fe = self.d_model, self.d_ff
-        inactive = self.num_layers * (self.num_experts - self.experts_per_token) * 3 * d * fe
+        inactive = self.stack_layers * (self.num_experts - self.experts_per_token) * 3 * d * fe
         return self.n_params() - inactive
 
     def reduced(self) -> "ModelConfig":
@@ -149,7 +185,7 @@ class ModelConfig:
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
-            num_layers=max(2, self.layer_group),
+            num_layers=max(2, self.layer_group) + self.first_k_dense,
             d_model=64,
             num_heads=4,
             num_kv_heads=max(1, min(self.num_kv_heads, 2)),
@@ -159,6 +195,11 @@ class ModelConfig:
             num_experts=min(self.num_experts, 4) if self.num_experts else 0,
             experts_per_token=min(self.experts_per_token, 2) if self.num_experts else 0,
             shared_expert_ff=64 if self.shared_expert_ff else 0,
+            dense_ff=96 if self.first_k_dense else 0,
+            kv_lora_rank=32 if self.is_mla else 0,
+            qk_nope_head_dim=16 if self.is_mla else 0,
+            qk_rope_head_dim=8 if self.is_mla else 0,
+            v_head_dim=12 if self.is_mla else 0,
             ssm_state=min(self.ssm_state, 8) if self.ssm_state else 0,
             ssm_chunk=8,
             sliding_window=min(self.sliding_window, 16) if self.sliding_window else 0,

@@ -37,6 +37,8 @@ REGISTRY: Dict[str, ModelConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}
 ALIASES = {
     "hymba": "hymba-1.5b",
     "moonshot": "moonshot-v1-16b-a3b",
+    "moonlight": "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b": "moonshot-v1-16b-a3b",
     "phi35-moe": "phi3.5-moe-42b-a6.6b",
     "phi3.5-moe": "phi3.5-moe-42b-a6.6b",
     "tinyllama": "tinyllama-1.1b",

@@ -47,10 +47,11 @@ from repro.train.steps import make_decode_step, make_prefill_step, make_train_st
 
 
 def _depth_variant(cfg, num_layers: int):
-    """Same architecture at reduced depth (used for cost extrapolation)."""
+    """Same architecture with ``num_layers`` scanned layers, leading dense
+    layers kept (used for cost extrapolation)."""
     import dataclasses
 
-    kw = {"num_layers": num_layers}
+    kw = {"num_layers": num_layers + cfg.first_k_dense}
     if cfg.encoder_layers:
         kw["encoder_layers"] = num_layers
     return dataclasses.replace(cfg, **kw)
@@ -169,7 +170,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, tcfg: Optional[Train
         del c2
     finally:
         set_cost_exact(False)
-    scale = (cfg.num_layers - l1) / (l2 - l1)
+    scale = (cfg.stack_layers - l1) / (l2 - l1)
     flops = f1 + (f2 - f1) * scale
     bbytes = b1 + (b2 - b1) * scale
     wire = w1 + (w2 - w1) * scale

@@ -97,8 +97,8 @@ def batch_shardings(cfg: ModelConfig, shape: ShapeConfig, mode: str, mesh: Mesh,
 def cache_pspec_tree(cfg: ModelConfig, caches, rules: MeshRules):
     """PartitionSpecs for decode caches.
 
-    KV sequence dim is context-parallel over the model axis (rules.context);
-    SSM/conv states shard d_inner over the model axis.
+    KV and latent sequence dims are context-parallel over the model axis
+    (rules.context); SSM/conv states shard d_inner over the model axis.
     """
     ctx = rules.context if rules.context else None
     bp = rules.batch
@@ -107,6 +107,8 @@ def cache_pspec_tree(cfg: ModelConfig, caches, rules: MeshRules):
         name = str(getattr(path[-1], "key", path[-1]))
         if name in ("k", "v"):
             return P(None, bp, ctx, None, None)
+        if name == "latent":
+            return P(None, bp, ctx, None)
         if name == "conv":
             return P(None, bp, None, rules.tensor)
         if name == "ssm":

@@ -17,6 +17,7 @@ from .transformer import (
     _dtype,
     _group_windows,
     _stack_params,
+    dense_cfg,
     init_params,
     abstract_params,
     stack_forward,
@@ -107,7 +108,7 @@ def forward_train(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:
     x, positions, kind, prefix_len, enc_kv = _prepare_inputs(cfg, params, batch)
     x, _ = stack_forward(
         cfg, params["layers"], x, positions, kind=kind, prefix_len=prefix_len,
-        enc_kv_layers=enc_kv,
+        enc_kv_layers=enc_kv, dense_layers=params.get("dense_layers"),
     )
     if cfg.family == VLM and prefix_len:
         x = x[:, prefix_len:]
@@ -119,13 +120,24 @@ def forward_train(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def init_caches(cfg: ModelConfig, batch: int, cache_len: int) -> Dict:
-    """Zero caches for decode: {'sub<i>': tree with leading (num_groups,)}."""
+    """Zero caches for decode: {'sub<i>': tree with leading (num_groups,)},
+    and {'dense_layers': ...} with leading (first_k_dense,).
+
+    Attention keeps K and V per head; latent attention keeps one row a
+    position, ``latent`` (..., cache_len, kv_lora_rank + qk_rope_head_dim)
+    = [RMSNorm(c), roped k_pe]."""
     dtype = _dtype(cfg)
     gp = cfg.layer_group
-    ng = cfg.num_layers // gp
+    ng = cfg.stack_layers // gp
     windows = _group_windows(cfg)
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     caches: Dict[str, Any] = {}
+    if cfg.is_mla:
+        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        caches["sub0"] = {"latent": jnp.zeros((ng, batch, cache_len, width), dtype)}
+        if cfg.first_k_dense:
+            caches["dense_layers"] = {"latent": jnp.zeros((cfg.first_k_dense, batch, cache_len, width), dtype)}
+        return caches
     for i in range(gp):
         sub: Dict[str, Any] = {}
         if cfg.family != SSM:
@@ -155,6 +167,7 @@ def forward_prefill(cfg: ModelConfig, params: Dict, batch: Dict, cache_len: int 
     x, caches = stack_forward(
         cfg, params["layers"], x, positions, kind=kind, prefix_len=prefix_len,
         enc_kv_layers=enc_kv, collect_caches=True, cache_len=cache_len,
+        dense_layers=params.get("dense_layers"),
     )
     logits = _logits(cfg, params, x[:, -1:])[:, 0]
     return logits, caches, enc_kv
@@ -164,11 +177,20 @@ def forward_prefill(cfg: ModelConfig, params: Dict, batch: Dict, cache_len: int 
 # decode
 # ---------------------------------------------------------------------------
 
-def _layer_decode(cfg: ModelConfig, p: Dict, x, cache: Dict, pos, window: int, enc_kv):
+def _layer_decode(cfg: ModelConfig, p: Dict, x, cache: Dict, pos, window: int, enc_kv, live=None):
+    """One layer for one token; returns (x, new cache, experts reached by
+    the slots ``live`` marks)."""
     new_cache: Dict[str, Any] = {}
+    reached = jnp.int32(0)
     h = rms_norm(x, p["attn_norm"], cfg.rms_eps)
     mix = jnp.zeros_like(x)
-    if "attn" in p:
+    if "attn" in p and cfg.is_mla:
+        out, new_cache["latent"] = attn_mod.mla_decode_attention(
+            p["attn"], h, cache["latent"], pos, num_heads=cfg.num_heads, nope=cfg.qk_nope_head_dim,
+            rope=cfg.qk_rope_head_dim, v_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta, eps=cfg.rms_eps,
+        )
+        mix = mix + out
+    elif "attn" in p:
         out, ck, cv = attn_mod.decode_attention(
             p["attn"], h, cache["k"], cache["v"], pos,
             num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
@@ -196,12 +218,14 @@ def _layer_decode(cfg: ModelConfig, p: Dict, x, cache: Dict, pos, window: int, e
         if "moe" in p:
             from . import moe as moe_mod
 
-            x = x + moe_mod.moe_apply(p["moe"], hf, cfg)
+            y, top_e = moe_mod.moe_layer(p["moe"], hf, cfg)
+            x = x + y
+            reached = moe_mod.experts_reached(top_e, cfg.num_experts, live)
         else:
             from .layers import mlp_apply
 
             x = x + mlp_apply(p["mlp"], hf, cfg.act)
-    return x, new_cache
+    return x, new_cache, reached
 
 
 def forward_decode(
@@ -211,19 +235,36 @@ def forward_decode(
     token: jax.Array,  # (B, 1) int32
     pos: jax.Array,  # (B,) absolute position of `token`
     enc_kv=None,  # (L,B,Senc,K,hd) x2 for enc-dec
-) -> Tuple[jax.Array, Dict]:
-    """One decode step; returns (logits (B,V), new caches)."""
+    stats: bool = False,
+    live=None,  # (B,) bool: the slots that carry a real token (default all)
+):
+    """One decode step; returns (logits (B,V), new caches), and with
+    ``stats`` also the call's counts (int32 scalars) over the ``live``
+    slots alone:
+
+    * ``moe.experts_reached``: distinct experts given at least one routed
+      live slot, summed over the MoE layers;
+    * ``mla.latent_positions``: cached positions attended (the token's own
+      included), summed over live slots and latent-attention layers."""
     x = _embed(cfg, params, token)
     if cfg.family == AUDIO:
         # absolute sinusoidal position for the new token
         table = sinusoidal_positions(int(caches["sub0"]["k"].shape[2]) + 1, cfg.d_model)
         x = x + table[pos][:, None].astype(x.dtype)
+    new_dense = []
+    if cfg.first_k_dense:
+        dcfg = dense_cfg(cfg)
+        for i in range(cfg.first_k_dense):
+            p_i = jax.tree.map(lambda t: t[i], params["dense_layers"])
+            c_i = jax.tree.map(lambda t: t[i], caches["dense_layers"])
+            x, nc, _ = _layer_decode(dcfg, p_i, x, c_i, pos, 0, None)
+            new_dense.append(nc)
     gp = cfg.layer_group
     windows = _group_windows(cfg)
     stacked = _stack_params(cfg, params["layers"])
-    xs = [stacked, caches]
+    xs = [stacked, {k: c for k, c in caches.items() if k != "dense_layers"}]
+    ng = cfg.stack_layers // gp
     if enc_kv is not None:
-        ng = cfg.num_layers // gp
         ek = enc_kv[0].reshape(ng, gp, *enc_kv[0].shape[1:])
         ev = enc_kv[1].reshape(ng, gp, *enc_kv[1].shape[1:])
         xs.append((ek, ev))
@@ -235,16 +276,28 @@ def forward_decode(
             gparams, caches_g = xs_g
             ekg = evg = None
         new_g = {}
+        n = jnp.int32(0)
         for i in range(gp):
             p_i = jax.tree.map(lambda t: t[i], gparams)
             ekv = (ekg[i], evg[i]) if ekg is not None else None
-            xcarry, nc = _layer_decode(cfg, p_i, xcarry, caches_g[f"sub{i}"], pos, windows[i], ekv)
+            xcarry, nc, r = _layer_decode(cfg, p_i, xcarry, caches_g[f"sub{i}"], pos, windows[i], ekv, live)
             new_g[f"sub{i}"] = nc
-        return xcarry, new_g
+            n = n + r
+        return xcarry, (new_g, n)
 
     from repro.utils.costmode import scan_unroll
 
-    ng = cfg.num_layers // gp
-    x, new_caches = jax.lax.scan(body, x, tuple(xs), unroll=scan_unroll(ng))
+    x, (new_caches, reached_g) = jax.lax.scan(body, x, tuple(xs), unroll=scan_unroll(ng))
+    if new_dense:
+        new_caches["dense_layers"] = jax.tree.map(lambda *c: jnp.stack(c), *new_dense)
     logits = _logits(cfg, params, x)[:, 0]
-    return logits, new_caches
+    if not stats:
+        return logits, new_caches
+    counts = {"moe.experts_reached": jnp.sum(reached_g, dtype=jnp.int32), "mla.latent_positions": jnp.int32(0)}
+    if cfg.is_mla:
+        s_cache = caches["sub0"]["latent"].shape[2]
+        attended = jnp.minimum(pos + 1, s_cache)
+        if live is not None:
+            attended = jnp.where(live, attended, 0)
+        counts["mla.latent_positions"] = cfg.num_layers * jnp.sum(attended, dtype=jnp.int32)
+    return logits, new_caches, counts

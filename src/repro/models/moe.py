@@ -21,6 +21,14 @@ Pipeline (per batch row, vmapped so the batch axis stays data-sharded):
 ``moe_dispatch="cumsum"`` selects the conventional one-hot-cumsum
 position computation as the ablation baseline (benchmarks table 2).
 
+Routing (``route``) is softmax top-k with the chosen probabilities
+renormalised, or, with ``router_scoring="sigmoid"`` (DeepSeek-V3
+``noaux_tc``), independent sigmoid scores: the top-k experts are chosen
+on score plus a per-expert ``e_score_correction_bias``, which chooses and
+never weights; the chosen unbiased scores are renormalised and scaled by
+``routed_scaling``.  A shared expert (``shared_expert_ff``) sees every
+token.
+
 **Gradients.** Every dispatch route is differentiable, including
 ``"merge_path_pallas"``: the sort acts on integer (expert_id, slot) pairs
 — a pure permutation with no float tangents — and the float scatter /
@@ -60,6 +68,8 @@ def moe_init(key, cfg: ModelConfig, dtype) -> Dict:
         "wi": dense_init(keys[2], (e, d, fe), d, dtype),
         "wo": dense_init(keys[3], (e, fe, d), fe, dtype),
     }
+    if cfg.router_scoring == "sigmoid":
+        p["e_score_correction_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.shared_expert_ff:
         p["shared"] = mlp_init(keys[4], d, cfg.shared_expert_ff, "silu", dtype)
     return p
@@ -148,13 +158,49 @@ def _positions_cumsum(flat_expert: jax.Array, e: int) -> jax.Array:
     return jnp.take_along_axis(pos, flat_expert[:, None], axis=1)[:, 0]
 
 
+def route(params: Dict, x: jax.Array, cfg: ModelConfig):
+    """x (..., d) -> (weights (..., k) float32, experts (..., k) int32)."""
+    k = cfg.experts_per_token
+    router_logits = x.astype(jnp.float32) @ params["router"]  # (..., E)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(router_logits)
+        _, top_e = jax.lax.top_k(scores + params["e_score_correction_bias"], k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+    else:
+        top_p, top_e = jax.lax.top_k(jax.nn.softmax(router_logits, axis=-1), k)
+    top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling != 1.0:
+        top_p = top_p * cfg.routed_scaling
+    return top_p, top_e
+
+
+def experts_reached(top_e: jax.Array, e: int, live: jax.Array | None = None) -> jax.Array:
+    """How many of the ``e`` experts ``top_e`` (B, ...) gives a slot of the
+    rows ``live`` (B,) marks (default all)."""
+    idx = top_e.reshape(top_e.shape[0], -1)
+    if live is not None:
+        idx = jnp.where(live[:, None], idx, e)  # out of range: dropped
+    return jnp.sum(jnp.zeros((e,), bool).at[idx.reshape(-1)].set(True, mode="drop"), dtype=jnp.int32)
+
+
 def moe_apply(
     params: Dict,
     x: jax.Array,
     cfg: ModelConfig,
     token_counts: jax.Array | None = None,
 ) -> jax.Array:
-    """x (B,S,d) -> (B,S,d). Batch axis stays sharded; experts tensor-sharded.
+    """x (B,S,d) -> (B,S,d): :func:`moe_layer`'s output alone."""
+    return moe_layer(params, x, cfg, token_counts)[0]
+
+
+def moe_layer(
+    params: Dict,
+    x: jax.Array,
+    cfg: ModelConfig,
+    token_counts: jax.Array | None = None,
+):
+    """x (B,S,d) -> (y (B,S,d), chosen experts (B,S,k)).  Batch axis stays
+    sharded; experts tensor-sharded.
 
     ``token_counts`` (optional, ``(B,)`` int32) marks each row's valid
     token count — padding tokens occupy the sequence tail.  With it, the
@@ -166,10 +212,7 @@ def moe_apply(
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
     cap = capacity(cfg, s)
-    router_logits = (x.astype(jnp.float32) @ params["router"])  # (B,S,E)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)  # (B,S,k)
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    top_p, top_e = route(params, x, cfg)  # (B,S,k)
 
     # Position-in-expert for ALL batch rows at once: the merge-path path is
     # one batched stable kv-sort (a single fused Alg. 2 pass across the
@@ -218,7 +261,7 @@ def moe_apply(
     y = jax.vmap(combine_row)(out_buf, flat_e, pos, kept, tok, top_p)
     if "shared" in params:
         y = y + mlp_apply(params["shared"], x, "silu")
-    return y.astype(x.dtype)
+    return y.astype(x.dtype), top_e
 
 
 def aux_load_balance_loss(router_logits: jax.Array, top_e: jax.Array, e: int) -> jax.Array:

@@ -1,15 +1,18 @@
 """Decoder stacks for all 10 assigned architectures.
 
 One functional implementation; families differ in the per-layer mixer
-(attention / mamba / both) and FFN (dense MLP / merge-path MoE).  Layers
-are scanned in homogeneous *groups* (``cfg.layer_group``): gemma3 scans
-groups of 6 (5 sliding + 1 global), hymba groups of 8 (7 sliding + 1
-global), everything else groups of 1 — keeping compiled HLO size
-O(group), not O(L).
+(attention / latent attention / mamba / both) and FFN (dense MLP /
+merge-path MoE).  Layers are scanned in homogeneous *groups*
+(``cfg.layer_group``): gemma3 scans groups of 6 (5 sliding + 1 global),
+hymba groups of 8 (7 sliding + 1 global), everything else groups of 1 —
+keeping compiled HLO size O(group), not O(L).  Leading dense layers
+(``cfg.first_k_dense``) live in ``params["dense_layers"]`` and run one by
+one before the scan over the ``cfg.stack_layers`` uniform layers.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -32,10 +35,26 @@ def _dtype(cfg: ModelConfig):
 # init
 # ---------------------------------------------------------------------------
 
+def dense_cfg(cfg: ModelConfig) -> ModelConfig:
+    """A leading dense layer's configuration: the expert FFN replaced by a
+    dense SwiGLU of ``cfg.dense_ff``."""
+    return dataclasses.replace(cfg, num_experts=0, experts_per_token=0, shared_expert_ff=0,
+                               d_ff=cfg.dense_ff, first_k_dense=0)
+
+
 def _attn_init(key, cfg: ModelConfig, dtype) -> Dict:
     d, hd = cfg.d_model, cfg.resolved_head_dim
     h, kv = cfg.num_heads, cfg.num_kv_heads
     k1, k2, k3, k4 = jax.random.split(key, 4)
+    if cfg.is_mla:
+        r, nope, rope, v = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        return {
+            "wq": dense_init(k1, (d, h * (nope + rope)), d, dtype),
+            "wkv_a": dense_init(k2, (d, r + rope), d, dtype),
+            "kv_norm": jnp.zeros((r,), jnp.float32),
+            "wkv_b": dense_init(k3, (r, h * (nope + v)), r, dtype),
+            "wo": dense_init(k4, (h * v, d), h * v, dtype),
+        }
     return {
         "wq": dense_init(k1, (d, h * hd), d, dtype),
         "wk": dense_init(k2, (d, kv * hd), d, dtype),
@@ -74,8 +93,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
         "final_norm": jnp.zeros((d,), jnp.float32),
     }
     cross = cfg.is_encoder_decoder
-    lkeys = jax.random.split(keys[1], cfg.num_layers)
+    lkeys = jax.random.split(keys[1], cfg.stack_layers)
     params["layers"] = jax.vmap(lambda k: _layer_init(k, cfg, dtype, cross))(lkeys)
+    if cfg.first_k_dense:
+        dkeys = jax.random.split(keys[6], cfg.first_k_dense)
+        params["dense_layers"] = jax.vmap(lambda k: _layer_init(k, dense_cfg(cfg), dtype, False))(dkeys)
     if not cfg.tie_embeddings:
         params["unembed"] = dense_init(keys[2], (d, cfg.vocab_size), d, dtype)
     if cfg.num_prefix_tokens:
@@ -116,7 +138,19 @@ def _layer_fwd(
     cache = {}
     h = rms_norm(x, p["attn_norm"], cfg.rms_eps)
     mix = jnp.zeros_like(x)
-    if "attn" in p:
+    if "attn" in p and cfg.is_mla:
+        out, latent = attn_mod.mla_attention(
+            p["attn"], h, num_heads=cfg.num_heads, nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
+            v_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta, eps=cfg.rms_eps, positions=positions,
+            kind=kind, prefix_len=prefix_len, chunk=cfg.attn_chunk, softcap=cfg.attn_logit_softcap,
+            force_blockwise=cfg.train_attn_blockwise and x.shape[1] > 1024,
+        )
+        mix = mix + out
+        if collect_cache:
+            take = min(x.shape[1], cache_len)
+            cache["latent"] = jnp.zeros((x.shape[0], cache_len, latent.shape[-1]), latent.dtype).at[
+                :, positions[-take:] % cache_len].set(latent[:, -take:])
+    elif "attn" in p:
         akw = dict(
             num_heads=cfg.num_heads,
             num_kv=cfg.num_kv_heads,
@@ -207,7 +241,7 @@ def _group_windows(cfg: ModelConfig):
 
 def _stack_params(cfg: ModelConfig, layers: Dict):
     gp = cfg.layer_group
-    ng = cfg.num_layers // gp
+    ng = cfg.stack_layers // gp
     return jax.tree.map(lambda t: t.reshape(ng, gp, *t.shape[1:]), layers)
 
 
@@ -222,14 +256,28 @@ def stack_forward(
     enc_kv_layers=None,  # (L, B, Senc, K, hd) x2 for enc-dec decoders
     collect_caches: bool = False,
     cache_len: int = 0,
+    dense_layers: Optional[Dict] = None,  # leading dense layers, stacked (first_k_dense, ...)
 ):
-    """Scan the layer stack; optionally collect decode caches."""
+    """Run the leading dense layers, then scan the layer stack; optionally
+    collect decode caches (the dense layers' under ``"dense_layers"``)."""
+    dense_caches = []
+    if dense_layers is not None:
+        dcfg = dense_cfg(cfg)
+
+        def dense_layer(xc, p_i):
+            return _layer_fwd(dcfg, p_i, xc, positions, window=0, kind=kind, prefix_len=prefix_len,
+                              collect_cache=collect_caches, cache_len=cache_len)
+
+        fn = jax.checkpoint(dense_layer) if cfg.remat else dense_layer
+        for i in range(cfg.first_k_dense):
+            x, cache = fn(x, jax.tree.map(lambda t: t[i], dense_layers))
+            dense_caches.append(cache)
     gp = cfg.layer_group
     windows = _group_windows(cfg)
     stacked = _stack_params(cfg, layers)
     if enc_kv_layers is not None:
         ek, ev = enc_kv_layers
-        ng = cfg.num_layers // gp
+        ng = cfg.stack_layers // gp
         ek = ek.reshape(ng, gp, *ek.shape[1:])
         ev = ev.reshape(ng, gp, *ev.shape[1:])
         xs = (stacked, ek, ev)
@@ -273,6 +321,8 @@ def stack_forward(
         body_fn = body
     from repro.utils.costmode import scan_unroll
 
-    ng = cfg.num_layers // gp
+    ng = cfg.stack_layers // gp
     x, caches = jax.lax.scan(body_fn, x, xs, unroll=scan_unroll(ng))
+    if collect_caches and dense_caches:
+        caches["dense_layers"] = jax.tree.map(lambda *c: jnp.stack(c), *dense_caches)
     return x, caches

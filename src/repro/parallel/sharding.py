@@ -139,6 +139,9 @@ _PARAM_RULES = {
     "xk": ("fsdp", "tensor"),
     "xv": ("fsdp", "tensor"),
     "xo": ("tensor", "fsdp"),
+    # latent attention: the shared down-projection, the per-head up-projection
+    "wkv_a": ("fsdp", None),  # (d, kv_lora_rank + rope)
+    "wkv_b": (None, "tensor"),  # (kv_lora_rank, H * (nope + v))
     # dense mlp
     "wi": ("fsdp", "tensor"),
     "wg": ("fsdp", "tensor"),
@@ -172,7 +175,7 @@ def param_spec(path: Tuple, leaf) -> P:
     rules = _MOE_RULES if in_moe else _PARAM_RULES
     logical = rules.get(name)
     if logical is None:
-        if name in ("scale", "attn_norm", "ffn_norm", "cross_norm", "final_norm", "norm"):
+        if name in ("scale", "attn_norm", "ffn_norm", "cross_norm", "final_norm", "norm", "kv_norm"):
             logical = (None,) * 1
         else:
             logical = ()

@@ -32,6 +32,17 @@ path replays exactly under the fault injector.
 ``run_until_done`` returns a :class:`ServingReport` summarising the
 outcome; ``report.ok()`` is the zero-degradation check CI asserts on a
 clean tree.
+
+Counters
+--------
+Every call of the jitted ``decode_step`` (its device ops are named
+``jit_decode_step/...`` in a profile) adds 1 to ``serving.decode_calls``
+on the host.  The model's own counts, ``moe.experts_reached`` and
+``mla.latent_positions`` (``forward_decode(..., stats=True)``), count the
+slots that carry a real token (the prompt's slot in a prompt-token call,
+the occupied slots in a lockstep call), are summed
+on the device over a tick's calls and read with the tick's lockstep
+logits, so a prompt-token call waits on nothing.
 """
 
 from __future__ import annotations
@@ -55,6 +66,9 @@ from . import sampler as sampler_mod
 # jitted once: run eagerly, the sampler's merge-sort loops are traced and
 # compiled again on every call (minutes per token at a 32K vocabulary)
 _topk_sample = jax.jit(sampler_mod.topk_sample, static_argnames=("k",))
+
+# the model's counts a decode call returns, in the order the engine sums them
+DEVICE_COUNTERS = ("moe.experts_reached", "mla.latent_positions")
 
 
 @dataclasses.dataclass
@@ -128,9 +142,31 @@ class ServingEngine:
         # timestamps count engine ticks (never wall time), so a
         # fault-injected run replays to a byte-identical trace.
         self.tick_clock = TickClock()
-        self._decode = jax.jit(
-            lambda params, caches, tok, pos: forward_decode(cfg, params, caches, tok, pos)
+        self._counts = jnp.zeros((len(DEVICE_COUNTERS),), jnp.int32)
+
+        def decode_step(params, caches, counts, tok, pos, live):
+            logits, caches, stats = forward_decode(cfg, params, caches, tok, pos, stats=True, live=live)
+            return logits, caches, counts + jnp.stack([stats[k] for k in DEVICE_COUNTERS])
+
+        self._decode_step = jax.jit(decode_step)
+
+    def _decode(self, token, pos, live: np.ndarray) -> jax.Array:
+        """One decode call: updates the caches and the device counts of the
+        ``live`` slots, returns the logits."""
+        get_telemetry().counter("serving.decode_calls").add(1)
+        logits, self.caches, self._counts = self._decode_step(
+            self.params, self.caches, self._counts, token, pos, jnp.asarray(live)
         )
+        return logits
+
+    def _read_counts(self, logits) -> np.ndarray:
+        """The logits on the host, with the device counts summed since the last read."""
+        logits_np, counts = jax.device_get((logits, self._counts))
+        self._counts = jnp.zeros_like(self._counts)
+        tel = get_telemetry()
+        for name, n in zip(DEVICE_COUNTERS, counts):
+            tel.counter(name).add(int(n))
+        return logits_np
 
     # -- request lifecycle ------------------------------------------------
 
@@ -191,7 +227,7 @@ class ServingEngine:
         for t, tok in enumerate(prompt):
             token = jnp.zeros((self.batch, 1), jnp.int32).at[slot, 0].set(int(tok))
             pos = jnp.asarray(np.where(np.arange(self.batch) == slot, t, self.pos), jnp.int32)
-            logits, self.caches = self._decode(self.params, self.caches, token, pos)
+            logits = self._decode(token, pos, np.arange(self.batch) == slot)
         self.pos[slot] = len(prompt)
         self.active[slot] = req
         self._last_logits = logits  # (B, V)
@@ -227,10 +263,10 @@ class ServingEngine:
         token = np.zeros((self.batch, 1), np.int32)
         for s in occupied:
             token[s, 0] = self.active[s].generated[-1]
-        logits, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(token), jnp.asarray(self.pos)
-        )
-        logits_np = np.asarray(logits)
+        live = np.zeros(self.batch, bool)
+        live[occupied] = True
+        logits = self._decode(jnp.asarray(token), jnp.asarray(self.pos), live)
+        logits_np = self._read_counts(logits)
         for s in occupied:
             req = self.active[s]
             self.pos[s] += 1

@@ -147,12 +147,10 @@ def test_n_params_sane():
     assert 5.5e9 < get_config("yi-6b").n_params() < 6.5e9
     assert 300e9 < get_config("nemotron-4-340b").n_params() < 380e9
     assert 6.5e9 < get_config("falcon-mamba-7b").n_params() < 8.5e9
-    # NB: the assigned pool config (48L x 64e x ff1408 gated) totals ~28.5B;
-    # the "16b" in the pool id refers to the HF release whose depth differs.
-    # We implement the assigned config verbatim (see configs/moonshot_*.py).
+    # Moonlight-16B-A3B as published: 15.96e9 parameters, 2.91e9 active
     m = get_config("moonshot-v1-16b-a3b")
-    assert 25e9 < m.n_params() < 31e9
-    assert 3.5e9 < m.n_active_params() < 5.5e9
+    assert abs(m.n_params() - 15.96e9) / 15.96e9 < 0.01
+    assert abs(m.n_active_params() - 2.91e9) / 2.91e9 < 0.01
     p = get_config("phi3.5-moe-42b-a6.6b")
     assert 39e9 < p.n_params() < 45e9
     assert 5.5e9 < p.n_active_params() < 8e9
