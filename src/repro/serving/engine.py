@@ -3,8 +3,11 @@
 A fixed pool of ``batch`` slots; finished/empty slots are refilled from
 the request queue (prefill), all occupied slots decode in lockstep (one
 jitted decode step per tick).  Per-slot absolute positions make the
-lockstep correct for ragged prompt lengths.  Sampling uses the
-merge-path top-k sampler.
+lockstep correct for ragged prompt lengths.  Sampling is one jitted
+device call a tick (``sample_step``, :func:`repro.serving.sampler.sample_batch`)
+over the logits the decode call left on the device: greedy rows take the
+argmax, top-k rows draw from ``jax.lax.top_k``'s candidates (the
+merge-path top-k's tie order), and the host reads back one token a slot.
 
 Graceful degradation
 --------------------
@@ -42,7 +45,14 @@ on the host.  The model's own counts, ``moe.experts_reached`` and
 slots that carry a real token (the prompt's slot in a prompt-token call,
 the occupied slots in a lockstep call), are summed
 on the device over a tick's calls and read with the tick's lockstep
-logits, so a prompt-token call waits on nothing.
+tokens, so a prompt-token call waits on nothing.
+
+Every call of the jitted ``sample_step`` (``jit_sample_step/...``) adds 1
+to ``serving.sample_calls``: one a lockstep tick over every occupied
+slot, and one after each refill for that slot's first token.  A call in
+which some live slot samples at a temperature above 0, and so runs the
+top-k branch, also adds 1 to ``serving.topk_calls``; an all-greedy call
+runs the argmax alone.
 """
 
 from __future__ import annotations
@@ -63,9 +73,14 @@ from repro.telemetry import WALL, TickClock, get_telemetry
 from repro.train.steps import _cast
 from . import sampler as sampler_mod
 
-# jitted once: run eagerly, the sampler's merge-sort loops are traced and
-# compiled again on every call (minutes per token at a 32K vocabulary)
-_topk_sample = jax.jit(sampler_mod.topk_sample, static_argnames=("k",))
+
+def sample_step(logits, key, temperature, row_k, k):
+    """Every slot's next token in one device call: ``(tokens (B,), next_key)``.
+    Jitted under this name, so that its device ops read ``jit_sample_step/...``."""
+    return sampler_mod.sample_batch(logits, key, temperature, k, row_k)
+
+
+_sample_step = jax.jit(sample_step, static_argnames=("k",))
 
 # the model's counts a decode call returns, in the order the engine sums them
 DEVICE_COUNTERS = ("moe.experts_reached", "mla.latent_positions")
@@ -159,14 +174,35 @@ class ServingEngine:
         )
         return logits
 
-    def _read_counts(self, logits) -> np.ndarray:
-        """The logits on the host, with the device counts summed since the last read."""
-        logits_np, counts = jax.device_get((logits, self._counts))
+    def _sample_slots(self, logits, live: np.ndarray) -> jax.Array:
+        """One ``sample_step`` over the ``live`` slots' rows of ``logits``,
+        which stay on the device; returns the ``(B,)`` tokens, still there."""
+        temperature = np.zeros(self.batch, np.float32)
+        row_k = np.ones(self.batch, np.int32)
+        for s in np.flatnonzero(live):
+            temperature[s] = self.active[s].temperature
+            row_k[s] = self.active[s].topk
+        row_k = np.clip(row_k, 1, logits.shape[-1])
+        drawn = live & (temperature > 0)
+        # k is static: the widest live top-k row's, and with none the widest
+        # live row's, so that an all-greedy call reuses a mixed call's program
+        k = int(row_k[drawn if drawn.any() else live].max())
+        tel = get_telemetry()
+        tel.counter("serving.sample_calls").add(1)
+        if drawn.any():
+            tel.counter("serving.topk_calls").add(1)
+        tokens, self.key = _sample_step(logits, self.key, jnp.asarray(temperature), jnp.asarray(row_k), k=k)
+        return tokens
+
+    def _read_tokens(self, tokens) -> np.ndarray:
+        """The tokens on the host, with the device counts summed since the
+        last read: one blocking read."""
+        tokens_np, counts = jax.device_get((tokens, self._counts))
         self._counts = jnp.zeros_like(self._counts)
         tel = get_telemetry()
         for name, n in zip(DEVICE_COUNTERS, counts):
             tel.counter(name).add(int(n))
-        return logits_np
+        return tokens_np
 
     # -- request lifecycle ------------------------------------------------
 
@@ -216,29 +252,29 @@ class ServingEngine:
 
     # -- decode -----------------------------------------------------------
 
-    def _fill_slot(self, slot: int, req: Request) -> None:
-        """Prefill one request into a slot by stepping its prompt tokens.
+    def _fill_slot(self, slot: int, req: Request) -> np.int32:
+        """Prefill one request into a slot by stepping its prompt tokens,
+        then sample its first token; returns that token, read back.
 
         Slot-wise decode-based prefill keeps the engine simple (batched
-        prompt prefill is the launch/dryrun `prefill` path); fine for the
-        CPU example scale this engine runs at.
+        prompt prefill is the launch/dryrun `prefill` path).  The first
+        token comes from the same ``sample_step`` as a lockstep tick's,
+        with only this slot live, on the last prompt call's logits.
         """
         prompt = req.prompt.astype(np.int32)
+        only = np.arange(self.batch) == slot
         for t, tok in enumerate(prompt):
             token = jnp.zeros((self.batch, 1), jnp.int32).at[slot, 0].set(int(tok))
-            pos = jnp.asarray(np.where(np.arange(self.batch) == slot, t, self.pos), jnp.int32)
-            logits = self._decode(token, pos, np.arange(self.batch) == slot)
+            pos = jnp.asarray(np.where(only, t, self.pos), jnp.int32)
+            logits = self._decode(token, pos, only)
         self.pos[slot] = len(prompt)
         self.active[slot] = req
-        self._last_logits = logits  # (B, V)
-        req._next_from_prefill = np.asarray(logits[slot])
+        return jax.device_get(self._sample_slots(logits, only))[slot]
 
-    def _sample(self, req: Request, logits_row: np.ndarray) -> int:
-        lrow = jnp.asarray(logits_row)[None]
-        if req.temperature <= 0:
-            return int(sampler_mod.greedy(lrow)[0])
-        self.key, sub = jax.random.split(self.key)
-        return int(_topk_sample(lrow, sub, k=req.topk, temperature=req.temperature)[0])
+    def _sample(self, req: Request, row) -> int:
+        """The token produced for ``req``: its slot's entry of the tokens
+        read back.  Every first and lockstep token is taken here."""
+        return int(row)
 
     def _tick_body(self) -> None:
         """Refill free slots, then one lockstep decode."""
@@ -246,8 +282,7 @@ class ServingEngine:
         for slot in range(self.batch):
             if self.active[slot] is None and self.pending:
                 req = self.pending.pop(0)
-                self._fill_slot(slot, req)
-                first = self._sample(req, req._next_from_prefill)
+                first = self._sample(req, self._fill_slot(slot, req))
                 req.generated.append(first)
                 tel.histogram("serving.ticks_to_first_token").record(
                     self.ticks - getattr(req, "_submit_tick", 0)
@@ -266,11 +301,11 @@ class ServingEngine:
         live = np.zeros(self.batch, bool)
         live[occupied] = True
         logits = self._decode(jnp.asarray(token), jnp.asarray(self.pos), live)
-        logits_np = self._read_counts(logits)
+        tokens = self._read_tokens(self._sample_slots(logits, live))
         for s in occupied:
             req = self.active[s]
             self.pos[s] += 1
-            nxt = self._sample(req, logits_np[s])
+            nxt = self._sample(req, tokens[s])
             req.generated.append(nxt)
             tel.histogram("serving.ticks_per_token").record(
                 self.ticks - getattr(req, "_last_tok_tick", self.ticks)

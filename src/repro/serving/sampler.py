@@ -1,5 +1,11 @@
 """Token samplers built on the merge-path top-k (paper integration #2).
 
+``sample_batch`` is what the serving engine calls: every slot of a tick,
+greedy and top-k rows alike, in one jitted device call on the logits the
+decode step left on the device (candidates from ``jax.lax.top_k``, whose
+tie order is the merge-path top-k's).  The functions below keep their
+backends as library API and as test oracles.
+
 ``topk_sample`` / ``topp_sample`` use the *batched* merge-path top-k
 (``repro.core.topk_batched``): all batch rows ride one fused kv-sort —
 every diagonal binary search of every row's merge rounds shares a single
@@ -52,6 +58,40 @@ from repro.core.merge_path import min_sentinel
 
 def greedy(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def sample_batch(
+    logits: jax.Array,  # (B, V)
+    key: jax.Array,
+    temperature: jax.Array,  # (B,) float32; a row at <= 0 is greedy
+    k: int,
+    row_k: Optional[jax.Array] = None,  # (B,) int32: a row's own top-k, 1..k (default k)
+) -> Tuple[jax.Array, jax.Array]:
+    """Every row's next token in one call: ``(tokens (B,) int32, next_key)``.
+
+    A row with ``temperature <= 0`` takes :func:`greedy`'s first maximum.
+    Any other row draws from its top ``row_k`` candidates at its own
+    temperature: the candidates are ``jax.lax.top_k``'s, whose order among
+    equal logits (smallest index first) is :func:`repro.core.topk_batched`'s,
+    so a row's candidate set is the merge-path top-k's.  One key is split
+    per call and drives every row's draw.  When no row samples, the top-k
+    branch is skipped inside the same program (``lax.cond``), so an
+    all-greedy batch pays for an argmax alone.
+    """
+    key, sub = jax.random.split(key)
+    best = greedy(logits)
+    drawn = temperature > 0
+
+    def draw(_):
+        vals, idx = jax.lax.top_k(logits, k)
+        scaled = vals.astype(jnp.float32) / jnp.where(drawn, temperature, 1.0)[:, None]
+        if row_k is not None:
+            scaled = jnp.where(jnp.arange(k) < row_k[:, None], scaled, min_sentinel(scaled.dtype))
+        choice = jax.random.categorical(sub, scaled)
+        tok = jnp.take_along_axis(idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+        return jnp.where(drawn, tok, best)
+
+    return jax.lax.cond(jnp.any(drawn), draw, lambda _: best, None), key
 
 
 def _topk_candidates(
